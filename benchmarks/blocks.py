@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from repro import configs
 from repro.configs.paper_blocks import blocks
 from repro.core.params import init_tree
-from repro.launch.dryrun import apply_variant
+from repro.configs import apply_variant
 from repro.models import transformer
 from benchmarks.common import compiled_temp_bytes, time_fn
 

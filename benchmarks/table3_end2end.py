@@ -9,7 +9,7 @@ import jax.numpy as jnp
 from benchmarks.common import compiled_temp_bytes, emit, time_fn
 from repro.configs.paper_blocks import opt_2_7b
 from repro.data.pipeline import DataConfig, synthetic_dataset
-from repro.launch.dryrun import apply_variant
+from repro.configs import apply_variant
 from repro.optim.adamw import OptimizerConfig
 from repro.train.trainer import Trainer, TrainerConfig
 

@@ -4,7 +4,6 @@ import dataclasses
 
 from benchmarks.blocks import bench_block, reduced
 from benchmarks.common import emit
-from repro.launch.dryrun import apply_variant
 
 
 def main(fast: bool = True) -> None:

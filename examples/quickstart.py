@@ -12,7 +12,7 @@ from repro import configs
 from repro.core import adapter
 from repro.core.params import count_params, init_tree, trainable_mask
 from repro.data.pipeline import DataConfig, synthetic_dataset
-from repro.launch.dryrun import apply_variant
+from repro.configs import apply_variant
 from repro.models import transformer
 from repro.optim.adamw import OptimizerConfig
 from repro.train.state import model_defs

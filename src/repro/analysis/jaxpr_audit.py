@@ -43,7 +43,7 @@ from typing import Callable, Dict, Iterator, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis.registry import Violation, audit
 

@@ -55,7 +55,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend import core as jcore
 from jax._src import source_info_util
 
 from repro.analysis import jaxpr_audit as ja
@@ -74,7 +74,7 @@ def aval_bytes(aval) -> int:
         if not isinstance(dim, int):
             return 0
         size *= dim
-    return size * jnp.dtype(dtype).itemsize
+    return size * getattr(dtype, "itemsize", 0)    # semaphores: 0
 
 
 def _aval_str(aval) -> str:

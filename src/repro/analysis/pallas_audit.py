@@ -13,11 +13,10 @@ TPU build.
 Rules:
   pallas.vmem-budget       2x (double-buffered) per-step block bytes +
                            scratch bytes > VMEM_BUDGET_BYTES
-  pallas.tile-divisibility a grid-blocked operand dim is not a multiple
-                           of its block dim (the wrappers zero-pad every
-                           operand to tile multiples *before* the
-                           pallas_call; a non-dividing shape here means a
-                           padding precondition was dropped)
+  pallas.tile-divisibility a VMEM block breaks the TPU's tiling rule:
+                           its last two dims must be divisible by (8, 128)
+                           or equal the array's dims (Mosaic refuses the
+                           kernel otherwise; interpret mode never does)
   pallas.scalar-prefetch   a kernel's scalar-prefetch operand count
                            drifted from its contract (grouped FFN
                            prefetches the plan index; decode FFN
@@ -40,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.jaxpr_audit import iter_eqns
+from repro.analysis.liveness import aval_bytes
 from repro.analysis.registry import Violation, audit
 
 # Per-core VMEM on current TPU generations (see the Pallas guide); one
@@ -49,7 +49,7 @@ VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 
 @dataclasses.dataclass(frozen=True)
 class BlockInfo:
-    block_shape: Tuple[Optional[int], ...]
+    block_shape: Tuple[int, ...]   # squeezed dims count as 1
     array_shape: Tuple[int, ...]
     dtype: str
     itemsize: int
@@ -58,8 +58,8 @@ class BlockInfo:
     @property
     def block_bytes(self) -> int:
         size = 1
-        for bdim, adim in zip(self.block_shape, self.array_shape):
-            size *= adim if bdim is None else int(bdim)
+        for bdim in self.block_shape:
+            size *= bdim
         return size * self.itemsize
 
 
@@ -88,22 +88,17 @@ class PallasCallInfo:
 def _scratch_nbytes(kernel_jaxpr, num_scratch: int) -> int:
     if not num_scratch:
         return 0
-    total = 0
-    for var in kernel_jaxpr.invars[-num_scratch:]:
-        aval = getattr(var.aval, "inner_aval", var.aval)
-        shape = getattr(aval, "shape", ())
-        dtype = getattr(aval, "dtype", jnp.float32)
-        size = 1
-        for dim in shape:
-            size *= int(dim)
-        total += size * jnp.dtype(dtype).itemsize
-    return total
+    return sum(aval_bytes(var.aval)
+               for var in kernel_jaxpr.invars[-num_scratch:])
 
 
-def collect_pallas_calls(fn: Callable, *args) -> List[PallasCallInfo]:
-    """Trace ``fn(*args)`` (ShapeDtypeStructs welcome) and decode every
-    pallas_call eqn, however deeply nested."""
-    jaxpr = jax.make_jaxpr(fn)(*args)
+def _block_dim(bdim) -> int:
+    """Extent of one block dim: ``Blocked(n)`` -> n, squeezed -> 1."""
+    return int(getattr(bdim, "block_size", 1))
+
+
+def calls_in_jaxpr(jaxpr) -> List[PallasCallInfo]:
+    """Decode every pallas_call eqn of ``jaxpr``, however deeply nested."""
     out = []
     for eqn in iter_eqns(jaxpr):
         if eqn.primitive.name != "pallas_call":
@@ -111,22 +106,29 @@ def collect_pallas_calls(fn: Callable, *args) -> List[PallasCallInfo]:
         gm = eqn.params["grid_mapping"]
         blocks = []
         for bm in gm.block_mappings:
-            asd = bm.array_shape_dtype
+            aval = bm.array_aval
             blocks.append(BlockInfo(
-                block_shape=tuple(bm.block_shape),
-                array_shape=tuple(asd.shape),
-                dtype=jnp.dtype(asd.dtype).name,
-                itemsize=jnp.dtype(asd.dtype).itemsize,
-                any_space="any" in str(bm.block_aval).lower()))
+                block_shape=tuple(_block_dim(d) for d in bm.block_shape),
+                array_shape=tuple(aval.shape),
+                dtype=jnp.dtype(aval.dtype).name,
+                itemsize=jnp.dtype(aval.dtype).itemsize,
+                any_space=str(getattr(bm.transformed_block_aval,
+                                      "memory_space", "")) == "any"))
+        kernel = eqn.params["jaxpr"]
         out.append(PallasCallInfo(
-            name=str(eqn.params.get("name_and_src_info", "pallas_call")),
+            name=str(kernel.debug_info.func_src_info),
             grid=tuple(int(g) for g in gm.grid),
             num_index_operands=int(gm.num_index_operands),
             num_scratch_operands=int(gm.num_scratch_operands),
             blocks=tuple(blocks),
-            scratch_bytes=_scratch_nbytes(eqn.params["jaxpr"],
-                                          gm.num_scratch_operands)))
+            scratch_bytes=_scratch_nbytes(kernel, gm.num_scratch_operands)))
     return out
+
+
+def collect_pallas_calls(fn: Callable, *args) -> List[PallasCallInfo]:
+    """Trace ``fn(*args)`` (ShapeDtypeStructs welcome) and decode every
+    pallas_call eqn."""
+    return calls_in_jaxpr(jax.make_jaxpr(fn)(*args))
 
 
 # ------------------------------------------------------------ rule bodies
@@ -143,21 +145,28 @@ def vmem_violations(calls: Sequence[PallasCallInfo], entry: str,
     return out
 
 
+# The TPU's (sublane, lane) tile: a block's last two dims must be
+# multiples of these or equal the array's own dims.
+TPU_TILE = (8, 128)
+
+
 def tile_divisibility_violations(calls: Sequence[PallasCallInfo],
                                  entry: str) -> List[Violation]:
     out = []
     for c in calls:
         for i, b in enumerate(c.blocks):
-            for bdim, adim in zip(b.block_shape, b.array_shape):
-                if bdim is None or not isinstance(adim, int):
-                    continue
-                if int(bdim) <= 0 or adim % int(bdim) != 0:
+            if b.any_space:
+                continue
+            pairs = list(zip(b.block_shape, b.array_shape))[-2:]
+            for (bdim, adim), tile in zip(pairs, TPU_TILE[-len(pairs):]):
+                if bdim != adim and bdim % tile:
                     out.append(Violation(
                         "pallas.tile-divisibility", entry,
-                        f"{c.short_name} operand {i}: array "
-                        f"{b.array_shape} not a multiple of block "
-                        f"{b.block_shape} — a zero-pad precondition "
-                        "was dropped"))
+                        f"{c.short_name} operand {i}: block "
+                        f"{b.block_shape} over array {b.array_shape} — "
+                        f"the last two block dims must be divisible by "
+                        f"{TPU_TILE} or equal the array's"))
+                    break
     return out
 
 

@@ -43,6 +43,21 @@ def get_smoke(name: str) -> ModelConfig:
     return _MODULES[name].smoke()
 
 
+def apply_variant(cfg: ModelConfig, variant: str) -> ModelConfig:
+    """The paper's fine-tuning baselines: "spt" (sparse MHA + routed FFN
+    + LoRA, as configured), "lora" (LoRA only) and "full" (dense, no
+    adapters)."""
+    if variant == "spt":
+        return cfg
+    if variant == "lora":
+        return cfg.with_spt(sparse_mha=False, routed_ffn=False)
+    if variant == "full":
+        from repro.core.lora import LoRAConfig
+        return cfg.with_spt(sparse_mha=False, routed_ffn=False,
+                            lora=LoRAConfig(enabled=False))
+    raise ValueError(variant)
+
+
 # (arch, shape) applicability: long_500k needs a sub-quadratic path —
 # SSM state, RG-LRU+local window, or SWA-bounded KV (DESIGN.md §5).
 _LONG_OK = {"mamba2-780m", "recurrentgemma-9b", "mixtral-8x22b",

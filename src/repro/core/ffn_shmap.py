@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import dispatch, lora
-from repro.core.compat import shard_map
 from repro.core.routed_ffn import (ACTIVATIONS, RoutedFFNConfig, route)
 
 
@@ -133,9 +132,9 @@ def routed_ffn_shmap(x: jax.Array, p: dict, cfg: RoutedFFNConfig,
                 scalar if not (use_lora and cfg.gated) else w_col,
                 scalar if not use_lora else w_row,
                 scalar if not use_lora else P(None, None))
-    fn = shard_map(inner, mesh=mesh, in_specs=in_specs,
-                   out_specs=(P(b_ax, model, None), P(), P()),
-                   check_vma=False)
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                       out_specs=(P(b_ax, model, None), P(), P()),
+                       check_vma=False)
     y, lb_loss, dropped = fn(x, p["router"], wi, wo, wg, li_b, li_c,
                              lg_b, lg_c, lo_b, lo_c)
     return y, {"lb_loss": lb_loss, "dropped": dropped}

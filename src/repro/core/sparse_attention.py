@@ -277,19 +277,24 @@ def sparse_mha(q: jax.Array, k: jax.Array, v: jax.Array,
     return out, aux
 
 
-def _eligibility(s: jax.Array, valid: jax.Array, l: int,
+def _eligibility(s: jax.Array, valid: jax.Array, l,
                  max_score: int) -> jax.Array:
     """The top-L set as a boolean mask (no indices): threshold bucket +
     most-recent tie budget — the selection semantics of bucket_select in
-    mask form.  All ops elementwise along the key axis (partition-friendly)."""
+    mask form.  All ops elementwise along the key axis (partition-friendly).
+    l: the budget, an int or traced budgets broadcastable to
+    s.shape[:-1] (per-row ragged-prefill budgets, as bucket_select's
+    l_dyn)."""
+    budget = jnp.asarray(l, jnp.int32)
     sm = jnp.where(valid, s.astype(jnp.int32), -1)
     counts = jnp.stack([jnp.sum((sm == v).astype(jnp.int32), axis=-1)
                         for v in range(max_score + 1)], axis=-1)
     ge = jnp.cumsum(counts[..., ::-1], axis=-1)[..., ::-1]
-    t = jnp.maximum(jnp.sum((ge >= l).astype(jnp.int32), axis=-1) - 1, 0)
+    t = jnp.maximum(jnp.sum((ge >= budget[..., None]).astype(jnp.int32),
+                            axis=-1) - 1, 0)
     ge_pad = jnp.concatenate([ge, jnp.zeros_like(ge[..., :1])], axis=-1)
     n_above = jnp.take_along_axis(ge_pad, (t + 1)[..., None], axis=-1)[..., 0]
-    need = (l - n_above)[..., None]
+    need = (budget - n_above)[..., None]
     above = sm > t[..., None]
     at_t = sm == t[..., None]
     rev_rank = jnp.cumsum(at_t[..., ::-1].astype(jnp.int32),
@@ -300,22 +305,26 @@ def _eligibility(s: jax.Array, valid: jax.Array, l: int,
 def sparse_mha_masked(q: jax.Array, k: jax.Array, v: jax.Array,
                       codebooks: jax.Array, cfg: SparseAttentionConfig,
                       scale: float, causal: bool = True,
-                      window: Optional[int] = None, q_offset: int = 0
+                      window: Optional[int] = None, q_offset: int = 0,
+                      seq_lengths: Optional[jax.Array] = None
                       ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Fused-kernel-equivalent execution (and its roofline analysis proxy):
     the top-L set is applied as a MASK on dense per-chunk logits — no (n, L)
     index matrix, no gathered K/V copies.  Selection semantics are identical
-    to sparse_mha/bucket_select; HBM traffic per chunk is O(chunk * nk)
-    instead of O(chunk * L * d) for the gather form (~d/8x less at L=n/8).
-    The Pallas kernel additionally skips ineligible key tiles on the MXU,
-    which XLA cannot express here — so this form's *compute* term is an
-    upper bound on the kernel's."""
+    to sparse_mha/bucket_select (seq_lengths as there); HBM traffic per
+    chunk is O(chunk * nk) instead of O(chunk * L * d) for the gather form
+    (~d/8x less at L=n/8), and its gradient is dense matmuls where the
+    gather form's is a scatter-add.  The Pallas kernel additionally skips
+    ineligible key tiles on the MXU, which XLA cannot express here — so
+    this form's *compute* term is an upper bound on the kernel's."""
     from repro.core.chunking import maybe_map
     from repro.sharding import shard
     b, hq, nq, d = q.shape
     _, hk, nk, _ = k.shape
     r = hq // hk
     l = top_l(nk, cfg, window)
+    if seq_lengths is not None:
+        l = top_l_dyn(seq_lengths, cfg, window).reshape(b, 1, 1)
     codes_q = pq.assign(q, codebooks)
     codes_k = pq.assign(k, codebooks)
     ckq = shard(jnp.repeat(codes_k, r, axis=1), "batch", "heads", None, None)
@@ -329,7 +338,15 @@ def sparse_mha_masked(q: jax.Array, k: jax.Array, v: jax.Array,
         cqc = jax.lax.dynamic_slice_in_dim(codes_q, start, chunk, axis=2)
         s = pq.match_scores(cqc, ckq, cfg.pq.num_codewords)
         s = shard(s, "batch", "heads", None, None)
-        eligible = _eligibility(s, mask[None, None], l, cfg.pq.num_books)
+        if cfg.select_granularity == "kvgroup":
+            # one selection per kv head, shared by its R query heads
+            sg = jnp.sum(s.reshape(b, hk, r, chunk, nk), axis=2)
+            eligible = _eligibility(sg, mask[None, None], l,
+                                    cfg.pq.num_books * r)
+            eligible = jnp.repeat(eligible, r, axis=1)
+        else:
+            eligible = _eligibility(s, mask[None, None], l,
+                                    cfg.pq.num_books)
         qc = jax.lax.dynamic_slice_in_dim(q, start, chunk, axis=2)
         logits = jnp.einsum("bhnd,bhmd->bhnm", qc, k_rep,
                             preferred_element_type=jnp.float32) * scale

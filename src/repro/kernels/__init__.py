@@ -5,7 +5,8 @@ Each kernel directory ships:
   ops.py    — jit'd public wrapper (custom_vjp: fused forward, ref backward)
   ref.py    — pure-jnp oracle (reuses the validated core/ implementations)
 
-Validated on CPU with interpret=True; TPU (v5e) is the compile target.
+Validated on CPU with interpret=True; tests/test_tpu_compile.py compiles
+each one for a described TPU v5e, and chip_smoke.py drives them on a chip.
 
 Every public wrapper takes ``interpret: Optional[bool] = None`` and routes
 it through ``resolve_interpret`` at the innermost pallas_call site: None

@@ -8,7 +8,11 @@ kernels cover the two serving regimes:
 grouped GEMMs: the capacity plan's ``index`` (core/dispatch.py) rides in as
 a scalar-prefetch operand, and each (Tc, d) token tile is DMA'd row-by-row
 from the raw (B, S, d) activations straight into VMEM — the (B, G, C, d)
-dispatch buffer the jnp path materializes in HBM never exists.  Per tile —
+dispatch buffer the jnp path materializes in HBM never exists.  The
+activations ride as f32 (B, S, 1, d), so each row DMA indexes two untiled
+leading dims and copies one whole (1, d) tile row: the TPU refuses a
+1-row slice of a tiled (S, d) array, and a 1-row bf16 slice of a packed
+(2, 128) tile.  Per tile —
 
     y[b, g] = act(x[index[b, g]] @ W_I[g] (+ LoRA)) @ W_O[g] (+ LoRA)
 
@@ -35,9 +39,12 @@ carry zero weights, so both are exact no-ops) instead of silently falling
 back to whole-dimension tiles that blow the VMEM budget at odd sizes.
 
 Grid (grouped): (B, G, C/Tc, F/Tf), F minor.  VMEM @ defaults (Tc=128,
-Tf=256, d<=6144): x tile 3.1 MB + weight slabs 2-3 x 3.1 MB bf16 + acc
-3.1 MB < 16 MB.  ``interpret=None`` derives from the backend (compiled on
-TPU, interpreter elsewhere).
+Tf=256): the (Tc, 1, d) f32 token tile pads each row to 8 sublanes
+(32*Tc*d B, 4 MiB at d=1024) + weight slabs 2-3 x 2*d*Tf B double-
+buffered + the (Tc, d) f32 accumulator.  ``_grouped_tiles`` halves Tf,
+then Tc, until a grid step fits 12 MiB, so wide models (d up to 6144)
+compile for v5e too (tests/test_tpu_compile.py).  ``interpret=None`` derives from the
+backend (compiled on TPU, interpreter elsewhere).
 """
 from __future__ import annotations
 
@@ -63,6 +70,39 @@ def _pad_tile(n: int, tile: int) -> int:
 
 def _pad_to(n: int, tile: int) -> int:
     return -(-n // tile) * tile
+
+
+# Of v5e's 16 MiB scoped VMEM default; the rest is Mosaic's own headroom.
+_VMEM_BUDGET = 12 * 1024 * 1024
+
+
+def _grouped_vmem_bytes(tc: int, tf: int, d: int, n_w: int, w_bytes: int,
+                        y_bytes: int, r: int) -> int:
+    """One grid step of the grouped kernel: double-buffered weight slabs
+    (n_w of (d, Tf)) and y block, the (Tc, 1, d) f32 token tile (each row
+    padded to 8 sublanes), the (Tc, d) f32 accumulator and, with LoRA
+    (rank r > 0), its rank-r blocks and (Tc, r) scratch."""
+    total = 2 * n_w * d * tf * w_bytes + 2 * tc * d * y_bytes
+    total += 8 * tc * d * 4 + tc * d * 4
+    if r:
+        total += 2 * (n_w * (d * r + r * tf) + tf * r + r * d) * w_bytes
+        total += tc * r * 4
+    return total
+
+
+def _grouped_tiles(c: int, f: int, d: int, n_w: int, w_bytes: int,
+                   y_bytes: int, r: int, tile_c: int, tile_f: int):
+    """(Tc, Tf) for the grouped kernel: the requested tiles, halved (Tf
+    first, down to 128, then Tc, down to 8) until one grid step fits the
+    VMEM budget.  Wide models (d of 2560-6144) need the smaller tiles."""
+    tc, tf = _pad_tile(c, tile_c), _pad_tile(f, tile_f)
+    fits = lambda: _grouped_vmem_bytes(tc, tf, d, n_w, w_bytes, y_bytes,
+                                       r) <= _VMEM_BUDGET
+    while not fits() and tf > 128 and tf % 256 == 0:
+        tf //= 2
+    while not fits() and tc > 8 and tc % 16 == 0:
+        tc //= 2
+    return tc, tf
 
 
 def _pad_f_operands(tf_pad, w_inner, w_outer, w_gate, lora_params):
@@ -96,7 +136,7 @@ def _make_grouped_kernel(act: str, s: int, tc: int, nft: int, gated: bool,
     def kernel(*refs):
         i = 0
         idx_ref = refs[i]; i += 1                        # scalar prefetch
-        x_hbm = refs[i]; i += 1                          # (B, S, d) in ANY
+        x_hbm = refs[i]; i += 1                          # (B, S, 1, d) f32
         wi_ref = refs[i]; i += 1
         wg_ref = None
         if gated:
@@ -112,7 +152,7 @@ def _make_grouped_kernel(act: str, s: int, tc: int, nft: int, gated: bool,
             lo_b = refs[i]; i += 1
             lo_c = refs[i]; i += 1
         y_ref = refs[i]; i += 1
-        xs_ref = refs[i]; i += 1                         # (Tc, d) token tile
+        xs_ref = refs[i]; i += 1                         # (Tc, 1, d) tile
         acc_ref = refs[i]; i += 1
         hb_ref = refs[i] if use_lora else None
         if use_lora:
@@ -135,8 +175,8 @@ def _make_grouped_kernel(act: str, s: int, tc: int, nft: int, gated: bool,
             # wait retires one row's worth of bytes; rows are same-sized).
             def row_copy(j):
                 row = jnp.minimum(idx_ref[bi, gi, ci * tc + j], s - 1)
-                return pltpu.make_async_copy(
-                    x_hbm.at[bi, pl.ds(row, 1)], xs_ref.at[pl.ds(j, 1)], sem)
+                return pltpu.make_async_copy(x_hbm.at[bi, row],
+                                             xs_ref.at[j], sem)
 
             def start_row(j, _):
                 row_copy(j).start()
@@ -152,7 +192,7 @@ def _make_grouped_kernel(act: str, s: int, tc: int, nft: int, gated: bool,
             if hb_ref is not None:
                 hb_ref[...] = jnp.zeros_like(hb_ref)
 
-        x = xs_ref[...].astype(jnp.float32)              # (Tc, d)
+        x = xs_ref[:, 0, :]                              # (Tc, d) f32
         f32 = jnp.float32
         dot = lambda a, b: jax.lax.dot_general(
             a, b, (((1,), (0,)), ((), ())), preferred_element_type=f32)
@@ -209,8 +249,11 @@ def grouped_ffn_kernel(x: jax.Array, index: jax.Array, w_inner: jax.Array,
     b, s, d = x.shape
     _, g, c = index.shape
     _, _, f = w_inner.shape
-    tc = _pad_tile(c, tile_c)
-    tf = _pad_tile(f, tile_f)
+    rank = (lora_params["lora_inner"]["b"].shape[-1]
+            if lora_params is not None else 0)
+    tc, tf = _grouped_tiles(c, f, d, 2 + (w_gate is not None),
+                            w_inner.dtype.itemsize, x.dtype.itemsize, rank,
+                            tile_c, tile_f)
     c_pad = _pad_to(c, tc) - c
     tf_pad = _pad_to(f, tf) - f
     if c_pad:                                 # pad slots are empty (-> S)
@@ -228,14 +271,14 @@ def grouped_ffn_kernel(x: jax.Array, index: jax.Array, w_inner: jax.Array,
     wo_spec = pl.BlockSpec((1, tf, d), lambda bi, gi, ci, fi, idx: (gi, fi, 0))
     y_spec = pl.BlockSpec((1, 1, tc, d),
                           lambda bi, gi, ci, fi, idx: (bi, gi, ci, 0))
-    inputs = [x, w_inner]
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY), wi_spec]
+    inputs = [x.astype(jnp.float32).reshape(b, s, 1, d), w_inner]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY), wi_spec]
     if gated:
         inputs.append(w_gate)
         in_specs.append(wi_spec)
     inputs.append(w_outer)
     in_specs.append(wo_spec)
-    scratch = [vmem((tc, d), x.dtype), vmem((tc, d), jnp.float32)]
+    scratch = [vmem((tc, 1, d), jnp.float32), vmem((tc, d), jnp.float32)]
     if use_lora:
         li = lora_params["lora_inner"]
         r = li["b"].shape[-1]
@@ -308,7 +351,7 @@ def _make_decode_kernel(act: str, n_active: int, nft: int, gated: bool,
                 hb_ref[...] = jnp.zeros_like(hb_ref)
 
         gt = gt_ref[bi, gi]
-        x = x_ref[...].astype(jnp.float32)               # (1, d)
+        x = x_ref[0].astype(jnp.float32)                 # (1, d)
         f32 = jnp.float32
         dot = lambda a, b: jax.lax.dot_general(
             a, b, (((1,), (0,)), ((), ())), preferred_element_type=f32)
@@ -336,7 +379,7 @@ def _make_decode_kernel(act: str, n_active: int, nft: int, gated: bool,
 
         @pl.when((gi == n_active - 1) & (fi == nft - 1))
         def _finish():
-            y_ref[...] = acc_ref[...].astype(y_ref.dtype)
+            y_ref[0] = acc_ref[...].astype(y_ref.dtype)
 
     return kernel
 
@@ -360,7 +403,11 @@ def decode_ffn_kernel(x: jax.Array, choice: jax.Array, gate: jax.Array,
     b, d = x.shape
     _, n_active = choice.shape
     _, _, f = w_inner.shape
-    tf = _pad_tile(f, tile_f)
+    rank = (lora_params["lora_inner"]["b"].shape[-1]
+            if lora_params is not None else 0)
+    _, tf = _grouped_tiles(1, f, d, 2 + (w_gate is not None),
+                           w_inner.dtype.itemsize, x.dtype.itemsize, rank,
+                           1, tile_f)
     tf_pad = _pad_to(f, tf) - f
     w_inner, w_outer, w_gate, lora_params = _pad_f_operands(
         tf_pad, w_inner, w_outer, w_gate, lora_params)
@@ -369,13 +416,15 @@ def decode_ffn_kernel(x: jax.Array, choice: jax.Array, gate: jax.Array,
     use_lora = lora_params is not None
     grid = (b, n_active, nft)
 
-    x_spec = pl.BlockSpec((1, d), lambda bi, gi, fi, ch, gt: (bi, 0))
+    # x and y ride as (B, 1, d): a (1, 1, d) block meets the TPU's
+    # (8, 128)-or-full tiling rule, a (1, d) block of (B, d) does not
+    x_spec = pl.BlockSpec((1, 1, d), lambda bi, gi, fi, ch, gt: (bi, 0, 0))
     wi_spec = pl.BlockSpec(
         (1, d, tf), lambda bi, gi, fi, ch, gt: (ch[bi, gi], 0, fi))
     wo_spec = pl.BlockSpec(
         (1, tf, d), lambda bi, gi, fi, ch, gt: (ch[bi, gi], fi, 0))
-    y_spec = pl.BlockSpec((1, d), lambda bi, gi, fi, ch, gt: (bi, 0))
-    inputs = [x, w_inner]
+    y_spec = x_spec
+    inputs = [x.reshape(b, 1, d), w_inner]
     in_specs = [x_spec, wi_spec]
     if gated:
         inputs.append(w_gate)
@@ -407,8 +456,9 @@ def decode_ffn_kernel(x: jax.Array, choice: jax.Array, gate: jax.Array,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
         out_specs=y_spec, scratch_shapes=scratch)
-    return pl.pallas_call(
+    y = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, d), x.dtype),
         interpret=interpret)(choice.astype(jnp.int32),
                              gate.astype(jnp.float32), *inputs)
+    return y.reshape(b, d)

@@ -1,11 +1,14 @@
-"""Public fused sparse MHA ops: Pallas forward, ref (jnp) backward.
+"""Public fused sparse MHA ops: Pallas forward, jnp backward.
 
 Train/prefill (`sparse_mha`): pq_assign kernel + bucket-histogram kernel +
-fused attention kernel.  Backward differentiates the reference
-implementation, which selects the identical top-L set (same integer
-thresholds and tie rule), so the gradient is consistent with the fused
-forward up to float associativity — the same contract the paper's unit
-tests check (§A.2, Figure 11).
+fused attention kernel.  Backward differentiates the masked jnp form
+(``core.sparse_attention.sparse_mha_masked``), which selects the identical
+top-L set (same integer thresholds and tie rule), so the gradient is
+consistent with the fused forward up to float associativity — the same
+contract the paper's unit tests check (§A.2, Figure 11).  The masked form
+and not the gather form: the gather's gradient scatter-adds every selected
+(query, key) pair's K and V rows, (B, Hq, n, L, d) of them, where the
+masked form's is dense matmuls.
 
 Serving decode (`sparse_mha_decode`): decode-threshold kernel + fused
 single-token attention kernel over the KV cache; inference-only, no VJP.
@@ -77,8 +80,9 @@ def _bwd(cfg, scale, causal, window, q_offset, interpret, res, g):
     q, k, v, codebooks = res
 
     def ref(q_, k_, v_, cb_):
-        out, _ = sa.sparse_mha(q_, k_, v_, cb_, cfg, scale, causal=causal,
-                               window=window, q_offset=q_offset)
+        out, _ = sa.sparse_mha_masked(q_, k_, v_, cb_, cfg, scale,
+                                      causal=causal, window=window,
+                                      q_offset=q_offset)
         return out
 
     _, vjp = jax.vjp(ref, q, k, v, codebooks)
@@ -118,8 +122,9 @@ def sparse_mha_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
     fuse=True (default): ONE Pallas kernel — grid step 0 derives the
     [t, need] thresholds from the whole code cache (pinned int8 codes
-    block; one-shot histogram, same integer math as the standalone
-    threshold kernel) straight into VMEM scratch, and the attention sweep
+    block, histogram folded tile by tile, same integer math as the
+    standalone threshold kernel) straight into VMEM scratch, and the
+    attention sweep
     pairs key tiles two-per-step, so the launch count, the thresholds HBM
     round-trip, AND half the grid steps disappear.  fuse=False: the
     original two-pass pipeline (decode-threshold kernel + attention
@@ -157,7 +162,7 @@ def sparse_mha_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     max_score = cfg.pq.num_books * (r if sum_rows else 1)
     codes_q = pq.assign(q, codebooks)                     # (B, Hq, 1, M)
     cqg = codes_q.reshape(b * hk, r, m)
-    ckg = codes_cache.astype(jnp.int32).reshape(b * hk, s, m)
+    ckg = codes_cache.reshape(b * hk, s, m)               # int8 stays int8
     qg = q.reshape(b * hk, r, d)
     kg = k_cache.reshape(b * hk, s, d)
     vg = v_cache.reshape(b * hk, s, d)
@@ -248,7 +253,7 @@ def dense_mha_decode_paged(q: jax.Array, k_pool: jax.Array,
     _, hk, _, _ = k_pool.shape
     r = hq // hk
     qg = q.reshape(b * hk, r, d)
-    kvv = kv_valid.astype(jnp.int32)
+    kvv = kv_valid.astype(jnp.int32)                      # (B, MP*ps)
     pt = jnp.maximum(page_table, 0)
     out = dense_decode_attention_paged_kernel(
         pt, qg, k_pool, v_pool, kvv, scale=scale, heads_per_batch=hk,

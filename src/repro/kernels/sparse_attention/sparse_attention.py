@@ -33,15 +33,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import resolve_interpret
 
 from repro.kernels.topl_select.topl_select import (
-    hist_counts, hist_reduce, vmem)
-
-
-def _scores(cq, ck):
-    m = cq.shape[1]
-    s = jnp.zeros((cq.shape[0], ck.shape[0]), jnp.int32)
-    for i in range(m):
-        s = s + (cq[:, i][:, None] == ck[:, i][None, :]).astype(jnp.int32)
-    return s
+    causal_mask, hist_counts, hist_reduce, lane_row, match_scores,
+    suffix_count, tile_positions, vmem)
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, cq_ref, ck_ref, thr_ref, o_ref,
@@ -53,63 +46,18 @@ def _attn_kernel(q_ref, k_ref, v_ref, cq_ref, ck_ref, thr_ref, o_ref,
 
     @pl.when(kj == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        tie_ref[...] = jnp.zeros_like(tie_ref)
+        _softmax_init(m_ref, l_ref, acc_ref, tie_ref)
 
-    cq = cq_ref[0]
-    ck = ck_ref[0]
-    s = _scores(cq, ck)                   # (Tq, Tk) int32
-    q_pos = q_offset + qi * tq + jax.lax.broadcasted_iota(jnp.int32, (tq,), 0)
-    k_pos = ki * tk + jax.lax.broadcasted_iota(jnp.int32, (tk,), 0)
-    valid = jnp.ones((tq, tk), bool)
-    if causal:
-        valid &= k_pos[None, :] <= q_pos[:, None]
-    if window is not None:
-        valid &= k_pos[None, :] > q_pos[:, None] - window
-    thr = thr_ref[0]                      # (Tq, 2)
-    t = thr[:, 0][:, None]
-    need = thr[:, 1][:, None]
-    sm = jnp.where(valid, s, -1)
-    above = sm > t
-    at_t = sm == t
-    # ties more recent than position b: taken so far + ties right of b in tile
-    rev_incl = jnp.cumsum(at_t[:, ::-1].astype(jnp.int32), axis=1)[:, ::-1]
-    rev_excl = rev_incl - at_t.astype(jnp.int32)
-    taken = tie_ref[:, 0][:, None]
-    elig_t = at_t & ((taken + rev_excl) < need)
-    eligible = above | elig_t
-    tie_ref[:, 0] += jnp.sum(elig_t.astype(jnp.int32), axis=1)
-
-    @pl.when(jnp.any(eligible))
-    def _block():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (Tq, Tk)
-        logits = jnp.where(eligible, logits, -jnp.inf)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1))
-        finite = m_new > -jnp.inf
-        m_safe = jnp.where(finite, m_new, 0.0)
-        alpha = jnp.where(finite, jnp.exp(m_prev - m_safe), 1.0)
-        p = jnp.where(finite[:, None], jnp.exp(logits - m_safe[:, None]), 0.0)
-        p = jnp.where(eligible, p, 0.0)
-        v = v_ref[0].astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
-        m_ref[:, 0] = m_new
+    s = match_scores(cq_ref[0], ck_ref[0])                # (Tq, Tk)
+    q_pos, k_pos = tile_positions(q_offset + qi * tq, ki * tk, tq, tk)
+    sm = jnp.where(causal_mask(q_pos, k_pos, causal, window), s, -1)
+    _attend_tile(sm, thr_ref[0], lambda: q_ref[0], lambda: k_ref[0],
+                 lambda: v_ref[0], m_ref, l_ref, acc_ref, tie_ref,
+                 scale=scale)
 
     @pl.when(kj == nkt - 1)
     def _finish():
-        l = l_ref[:, 0]
-        out = acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]
-        out = jnp.where((l > 0)[:, None], out, 0.0)
-        o_ref[0] = out.astype(o_ref.dtype)
+        _write_out(o_ref, l_ref, acc_ref)
 
 
 def sparse_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -183,46 +131,51 @@ def _attend_tile(sm, thr, q_get, k_get, v_get,
     [t, need]; q/k/v_get: thunks returning the (R, dh)/(Tk, dh)/(Tk, dh)
     tiles (deferred so a fully ineligible tile skips the VMEM reads and
     MXU work via pl.when)."""
-    t = thr[:, 0][:, None]
-    need = thr[:, 1][:, None]
+    t = thr[:, 0:1]
+    need = thr[:, 1:2]
     above = sm > t
     at_t = sm == t
     # ties more recent (higher slot index) than position b: taken so far in
     # previously visited (newer) tiles + ties right of b inside this tile
-    rev_incl = jnp.cumsum(at_t[:, ::-1].astype(jnp.int32), axis=1)[:, ::-1]
-    rev_excl = rev_incl - at_t.astype(jnp.int32)
-    taken = tie_ref[:, 0][:, None]
-    elig_t = at_t & ((taken + rev_excl) < need)
+    rev_excl = suffix_count(at_t) - at_t.astype(jnp.int32)
+    elig_t = at_t & ((tie_ref[...] + rev_excl) < need)
     eligible = above | elig_t             # (R_out, Tk)
-    tie_ref[:, 0] += jnp.sum(elig_t.astype(jnp.int32), axis=1)
+    tie_ref[...] += jnp.sum(elig_t.astype(jnp.int32), axis=1, keepdims=True)
 
     @pl.when(jnp.any(eligible))
     def _block():
-        q = q_get().astype(jnp.float32)               # (R, dh)
-        k = k_get().astype(jnp.float32)               # (Tk, dh)
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # (R, Tk)
-        logits = jnp.where(eligible, logits, -jnp.inf)        # bcast if kvgroup
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1))
-        finite = m_new > -jnp.inf
-        m_safe = jnp.where(finite, m_new, 0.0)
-        alpha = jnp.where(finite, jnp.exp(m_prev - m_safe), 1.0)
-        p = jnp.where(finite[:, None], jnp.exp(logits - m_safe[:, None]), 0.0)
-        p = jnp.where(eligible, p, 0.0)
-        v = v_get().astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
-        m_ref[:, 0] = m_new
+        _online_softmax_tile(q_get, k_get, v_get, eligible, m_ref, l_ref,
+                             acc_ref, scale=scale)
+
+
+def _online_softmax_tile(q_get, k_get, v_get, keep, m_ref, l_ref, acc_ref, *,
+                         scale):
+    """Fold one key tile into the flash-style accumulators; ``keep``
+    ((R or 1, Tk) bool) masks the logits.  Statistics are (R, 1) columns
+    so every reduction keeps its lane dim (Mosaic-friendly)."""
+    q = q_get().astype(jnp.float32)                   # (R, dh)
+    k = k_get().astype(jnp.float32)                   # (Tk, dh)
+    logits = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale   # (R, Tk)
+    logits = jnp.where(keep, logits, -jnp.inf)        # bcast if kvgroup
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
+    finite = m_new > -jnp.inf
+    m_safe = jnp.where(finite, m_new, 0.0)
+    alpha = jnp.where(finite, jnp.exp(m_prev - m_safe), 1.0)
+    p = jnp.where(finite & keep, jnp.exp(logits - m_safe), 0.0)
+    v = v_get().astype(jnp.float32)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    m_ref[...] = m_new
 
 
 def _write_out(o_ref, l_ref, acc_ref):
-    l = l_ref[:, 0]
-    out = acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]
-    out = jnp.where((l > 0)[:, None], out, 0.0)
+    l = l_ref[...]
+    out = acc_ref[...] / jnp.maximum(l, 1e-30)
+    out = jnp.where(l > 0, out, 0.0)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -235,13 +188,7 @@ def _decode_attn_kernel(q_ref, k_ref, v_ref, cq_ref, ck_ref, thr_ref,
     def _init():
         _softmax_init(m_ref, l_ref, acc_ref, tie_ref)
 
-    cq = cq_ref[0]                        # (R, M)
-    ck = ck_ref[0]                        # (Tk, M)
-    s = _scores(cq, ck)                   # (R, Tk)
-    if sum_rows:                          # kvgroup: one shared selection
-        s = jnp.sum(s, axis=0, keepdims=True)         # (1, Tk)
-    valid = valid_ref[0] != 0             # (Tk,)
-    sm = jnp.where(valid[None, :], s, -1)
+    sm = _mask_scores(cq_ref[0], ck_ref[0], valid_ref[0], sum_rows)
     _attend_tile(sm, thr_ref[0],
                  lambda: q_ref[0], lambda: k_ref[0], lambda: v_ref[0],
                  m_ref, l_ref, acc_ref, tie_ref, scale=scale)
@@ -270,7 +217,9 @@ def sparse_decode_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
     thresholds: (G, R_out, 2) [t, need] from decode_topl_thresholds_kernel
     (R_out = 1 under the shared "kvgroup" selection, R per-head).
     kv_valid: (B, S) nonzero = cache slot participates; both plain causal
-    caches and ring-buffer sliding-window caches reduce to this mask.
+    caches and ring-buffer sliding-window caches reduce to this mask.  It
+    rides as (B, 1, S), whose unit sublane dim lets a (1, 1, Tk) block
+    meet the TPU's (8, 128)-or-full tiling rule.
 
     Key tiles are visited newest-slot-first so the most-recent-ties-first
     budget is consumed in canonical order; tiles with no eligible key skip
@@ -299,7 +248,8 @@ def sparse_decode_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, r, m), lambda gi, kj: (gi, 0, 0)),
             pl.BlockSpec((1, tk, m), lambda gi, kj: (gi, nkt - 1 - kj, 0)),
             pl.BlockSpec((1, r_out, 2), lambda gi, kj: (gi, 0, 0)),
-            pl.BlockSpec((1, tk), lambda gi, kj: (gi // hpb, nkt - 1 - kj)),
+            pl.BlockSpec((1, 1, tk),
+                         lambda gi, kj: (gi // hpb, 0, nkt - 1 - kj)),
         ],
         out_specs=pl.BlockSpec((1, r, dh), lambda gi, kj: (gi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((g, r, dh), q.dtype),
@@ -310,7 +260,7 @@ def sparse_decode_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
             vmem((r_out, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(q, k, v, codes_q, codes_k, thresholds, kv_valid)
+    )(q, k, v, codes_q, codes_k, thresholds, lane_row(kv_valid))
 
 
 # ----------------------------------------------- fused one-pass decode
@@ -336,33 +286,22 @@ def _pair_of(nkt: int) -> int:
 
 
 def _mask_scores(cq, ck, valid, sum_rows):
-    """Codes + validity -> (R_out, N) masked match scores (-1 = dead)."""
-    s = _scores(cq, ck)
+    """Codes (R, M) x (N, M) + validity (1, N) -> (R_out, N) masked match
+    scores (-1 = dead)."""
+    s = match_scores(cq, ck)
     if sum_rows:
         s = jnp.sum(s, axis=0, keepdims=True)
-    return jnp.where(valid[None, :], s, -1)
+    return jnp.where(valid != 0, s, -1)
 
 
-def _fused_step(kj, q_ref, thresholds, tiles, o_ref, m_ref, l_ref, acc_ref,
-                tie_ref, thr_ref, *, scale, sum_rows, nsteps):
-    """Shared step body of the fused one-pass decode kernels (contiguous
-    and paged).  Step 0 computes the FULL-cache threshold in one shot
-    (thresholds(): identical integer math to the standalone threshold
-    kernel, so the two-pass tier stays bit-identical) and every step then
-    replays its `tiles` — [(masked-score thunk, k thunk, v thunk), ...]
-    newest slot first — through the exact two-pass attention body."""
-    @pl.when(kj == 0)
-    def _init():
-        _softmax_init(m_ref, l_ref, acc_ref, tie_ref)
-        thr_ref[...] = thresholds()
+def _row_hist(n_tiles, tile_scores, r_out, max_score):
+    """Score histogram of a whole cache row, folded one tile at a time so
+    no (R, N) score row is ever live: tile_scores(i) -> (R_out, Tk)."""
+    def body(i, hist):
+        return hist + hist_counts(tile_scores(i), max_score)
 
-    for sm_get, k_get, v_get in tiles:
-        _attend_tile(sm_get(), thr_ref[...], lambda: q_ref[0], k_get, v_get,
-                     m_ref, l_ref, acc_ref, tie_ref, scale=scale)
-
-    @pl.when(kj == nsteps - 1)
-    def _finish():
-        _write_out(o_ref, l_ref, acc_ref)
+    return jax.lax.fori_loop(0, n_tiles, body,
+                             jnp.zeros((r_out, max_score + 1), jnp.int32))
 
 
 def _fused_decode_kernel(q_ref, k_ref, v_ref, cq_ref, ck_ref, valid_ref,
@@ -370,31 +309,31 @@ def _fused_decode_kernel(q_ref, k_ref, v_ref, cq_ref, ck_ref, valid_ref,
                          scale, l, max_score, sum_rows, tk, pair, nsteps):
     kj = pl.program_id(1)
     cq = cq_ref[0]
-    ck_all = ck_ref[0]                    # (nk, M) — whole (padded) cache
-    valid_all = valid_ref[0] != 0         # (nk,)
 
-    def thresholds():
-        sm_all = _mask_scores(cq, ck_all, valid_all, sum_rows)
-        return hist_reduce(hist_counts(sm_all, max_score), l)
+    def tile_scores(ti):                  # key tile ti of the pinned row
+        start = pl.multiple_of(ti * tk, tk)
+        return _mask_scores(cq, ck_ref[0, pl.ds(start, tk), :],
+                            valid_ref[0, :, pl.ds(start, tk)], sum_rows)
+
+    @pl.when(kj == 0)
+    def _init():
+        # the FULL-cache threshold: identical integer math to the
+        # standalone threshold kernel, so the two-pass tier stays
+        # bit-identical
+        _softmax_init(m_ref, l_ref, acc_ref, tie_ref)
+        thr_ref[...] = hist_reduce(_row_hist(nsteps * pair, tile_scores,
+                                             thr_ref.shape[0], max_score), l)
 
     base = (nsteps - 1 - kj) * pair       # oldest tile of this step's block
+    for h in reversed(range(pair)):       # newest slot first
+        sl = slice(h * tk, (h + 1) * tk)
+        _attend_tile(tile_scores(base + h), thr_ref[...], lambda: q_ref[0],
+                     lambda sl=sl: k_ref[0, sl], lambda sl=sl: v_ref[0, sl],
+                     m_ref, l_ref, acc_ref, tie_ref, scale=scale)
 
-    def tile(h):
-        start = (base + h) * tk
-
-        def sm_get():
-            ck = jax.lax.dynamic_slice_in_dim(ck_all, start, tk, axis=0)
-            valid = jax.lax.dynamic_slice_in_dim(valid_all, start, tk)
-            return _mask_scores(cq, ck, valid, sum_rows)
-
-        return (sm_get,
-                lambda: k_ref[0, h * tk:(h + 1) * tk],
-                lambda: v_ref[0, h * tk:(h + 1) * tk])
-
-    _fused_step(kj, q_ref, thresholds, [tile(h) for h in
-                                        reversed(range(pair))],
-                o_ref, m_ref, l_ref, acc_ref, tie_ref, thr_ref,
-                scale=scale, sum_rows=sum_rows, nsteps=nsteps)
+    @pl.when(kj == nsteps - 1)
+    def _finish():
+        _write_out(o_ref, l_ref, acc_ref)
 
 
 def fused_sparse_decode_attention_kernel(
@@ -405,11 +344,12 @@ def fused_sparse_decode_attention_kernel(
     """One-pass decode: thresholds fused into the attention kernel, and
     the key axis swept at HALF the two-pass grid length.
 
-    The whole (padded) code cache and validity row ride as pinned blocks —
-    M int8 lanes per slot vs 2*dh f32/bf16 lanes of K+V, so they are the
-    cheap operands — and grid step 0 computes the full score histogram and
-    the (R_out, 2) [t, need] thresholds in ONE shot into VMEM scratch: no
-    prologue steps, no thresholds HBM round-trip, no second kernel launch.
+    The whole (padded) int8 code row and validity row ride as pinned
+    blocks — M int8 lanes per slot vs 2*dh f32/bf16 lanes of K+V, so they
+    are the cheap operands — and grid step 0 folds the full score
+    histogram one key tile at a time into the (R_out, 2) [t, need]
+    thresholds in VMEM scratch: no prologue steps, no thresholds HBM
+    round-trip, no second kernel launch.
     Each step then reads one (pair*Tk) K/V block and replays its `pair`
     sub-tiles newest-slot-first through the two-pass kernels' attention
     body, so the eligibility rule, tie budget, and online-softmax
@@ -422,8 +362,9 @@ def fused_sparse_decode_attention_kernel(
     and half the grid steps (double-width K/V DMA per step).
 
     Shapes as sparse_decode_attention_kernel; nk must be a multiple of
-    pair*tile_k (the ops wrapper zero-pads, dead slots carry kv_valid=0);
-    the pinned codes block keeps VMEM at O(nk*M) int8 — ~64 KB at S=8192.
+    pair*tile_k (the ops wrapper zero-pads, dead slots carry kv_valid=0).
+    The pinned int8 codes block pads its M lanes to 128 in VMEM: 128 B per
+    slot, 4 MiB at S=32k (double-buffered 8 MiB).
     """
     interpret = resolve_interpret(interpret)
     g, r, dh = q.shape
@@ -451,60 +392,52 @@ def fused_sparse_decode_attention_kernel(
                          lambda gi, kj: (gi, nsteps - 1 - kj, 0)),
             pl.BlockSpec((1, r, m), lambda gi, kj: (gi, 0, 0)),
             pl.BlockSpec((1, nk, m), lambda gi, kj: (gi, 0, 0)),
-            pl.BlockSpec((1, nk), lambda gi, kj: (gi // hpb, 0)),
+            pl.BlockSpec((1, 1, nk), lambda gi, kj: (gi // hpb, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, r, dh), lambda gi, kj: (gi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((g, r, dh), q.dtype),
         scratch_shapes=_decode_scratch(r, r_out, dh),
         interpret=interpret,
-    )(q, k, v, codes_q, codes_k, kv_valid)
+    )(q, k, v, codes_q, codes_k, lane_row(kv_valid))
 
 
 # ----------------------------------------------- kernel-native paging
 def _fused_decode_paged_kernel(pt_ref, q_ref, k_ref, v_ref, cq_ref,
-                               ckslab_ref, valid_ref, o_ref, m_ref, l_ref,
-                               acc_ref, tie_ref, thr_ref, *, scale, l,
-                               max_score, sum_rows, tk, pair, nsteps, hpb,
-                               mp, ps):
+                               ckh_ref, vh_ref, cka_ref, va_ref, o_ref,
+                               m_ref, l_ref, acc_ref, tie_ref, thr_ref,
+                               hist_ref, *, scale, l, max_score, sum_rows,
+                               tk, pair, nsteps, mp):
     kj = pl.program_id(1)
-    gi_b = pl.program_id(0) // hpb
     cq = cq_ref[0]
-    # Codes + validity ride ONCE as whole-pool-slab / whole-row pinned
-    # blocks (M int8 lanes vs 2*dh f32 lanes of K+V — the cheap operands);
-    # logical tiles are sliced in-register via the scalar-prefetched page
-    # table, never gathered from HBM.
-    slab = ckslab_ref[:, 0].astype(jnp.int32)             # (P, ps, M)
-    pages = jax.lax.dynamic_slice_in_dim(
-        pt_ref[...], gi_b, 1, axis=0)[0]                  # (MP,)
-    valid_all = valid_ref[0] != 0                         # (MP*ps,)
 
-    def thresholds():
-        ck_all = jnp.take(slab, pages, axis=0).reshape(mp * ps, -1)
-        return hist_reduce(hist_counts(
-            _mask_scores(cq, ck_all, valid_all, sum_rows), max_score), l)
+    @pl.when(kj == 0)
+    def _init():
+        _softmax_init(m_ref, l_ref, acc_ref, tie_ref)
+        hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    base = (nsteps - 1 - kj) * pair       # oldest view tile in this block
+    @pl.when(kj < mp)                     # histogram phase: page kj
+    def _hist():
+        hist_ref[...] += hist_counts(
+            _mask_scores(cq, ckh_ref[0, 0], vh_ref[0], sum_rows), max_score)
 
-    def tile(h):
-        vt = base + h                     # logical view tile index
+    @pl.when(kj == mp - 1)
+    def _thresholds():
+        thr_ref[...] = hist_reduce(hist_ref[...], l)
 
-        def sm_get():
-            page = jax.lax.dynamic_index_in_dim(pages, vt // (ps // tk),
-                                                keepdims=False)
-            ck = jax.lax.dynamic_slice(
-                slab, (page, (vt % (ps // tk)) * tk, 0),
-                (1, tk, slab.shape[-1]))[0]
-            valid = jax.lax.dynamic_slice_in_dim(valid_all, vt * tk, tk)
-            return _mask_scores(cq, ck, valid, sum_rows)
+    @pl.when(kj >= mp)                    # attention phase, newest first
+    def _attend():
+        for h in reversed(range(pair)):
+            sl = slice(h * tk, (h + 1) * tk)
+            sm = _mask_scores(cq, cka_ref[0, 0, sl], va_ref[0, :, sl],
+                              sum_rows)
+            _attend_tile(sm, thr_ref[...], lambda: q_ref[0],
+                         lambda sl=sl: k_ref[0, 0, sl],
+                         lambda sl=sl: v_ref[0, 0, sl],
+                         m_ref, l_ref, acc_ref, tie_ref, scale=scale)
 
-        return (sm_get,
-                lambda: k_ref[0, 0, h * tk:(h + 1) * tk],
-                lambda: v_ref[0, 0, h * tk:(h + 1) * tk])
-
-    _fused_step(kj, q_ref, thresholds, [tile(h) for h in
-                                        reversed(range(pair))],
-                o_ref, m_ref, l_ref, acc_ref, tie_ref, thr_ref,
-                scale=scale, sum_rows=sum_rows, nsteps=nsteps)
+    @pl.when(kj == mp + nsteps - 1)
+    def _finish():
+        _write_out(o_ref, l_ref, acc_ref)
 
 
 def fused_sparse_decode_attention_paged_kernel(
@@ -519,26 +452,27 @@ def fused_sparse_decode_attention_paged_kernel(
     (page_table[slot, block // blocks_per_page], head, offset) — no
     gathered (B, Hk, S, .) view of the pool ever materializes.
 
-    Thresholds are computed in grid step 0 from the codes POOL SLAB (every
-    page of this head's code pool pinned in VMEM — int8, M lanes, so the
-    slab is ~2*dh*itemsize/M times smaller than K+V) by gathering the MP
-    logical pages in-register via the prefetched table; identical integer
-    math to the standalone threshold kernel.  Each step then reads one
-    (pair*Tk) K/V block of a single page and replays its sub-tiles
-    newest-slot-first through the shared attention body — pair = 2 when
-    tiles_per_page is even, so blocks never straddle a page.  Per-tile
-    code/validity tiles are sliced in-register from the same pinned slab /
-    full row (the cheap operands ride once; only K/V stream per-step).
+    Grid (G, MP + nsteps).  The first MP steps stream the slot's own code
+    pages, one (page_size, M) block per step, and fold each into a score
+    histogram in VMEM scratch; the last of them derives the [t, need]
+    thresholds (identical integer math to the standalone threshold
+    kernel).  Fast memory holds one page of codes whatever the pool size.
+    The remaining nsteps steps each read one (pair*Tk) K/V/code block of a
+    single page and replay its sub-tiles newest-slot-first through the
+    shared attention body — pair = 2 when tiles_per_page is even, so
+    blocks never straddle a page.  During the histogram phase the K/V
+    index_maps already point at the first attention block, so the
+    pipeline fetches it once.
 
     page_table: (B, MP) int32, clamped non-negative by the caller (the
     repo-wide convention: unallocated -> page 0, whose garbage rows carry
     kv_valid == 0).  q/codes_q: (G, R, .) with G = B*Hk; pools:
-    (P, Hk, page_size, .); kv_valid: (B, MP*page_size) in view coordinates.
-    The tile size divides page_size so no tile straddles a page boundary;
-    the sweep visits the same Tk tiles in the same newest-first order as
-    the contiguous kernel, so given equal tile_k the output is
-    bit-identical to running the contiguous fused kernel (or the two-pass
-    pair) over the gathered view.
+    (P, Hk, page_size, .); kv_valid: (B, MP*page_size) in view
+    coordinates.  The tile size divides page_size so no tile straddles a
+    page boundary; the sweep visits the same Tk tiles in the same
+    newest-first order as the contiguous kernel, so given equal tile_k the
+    output is bit-identical to running the contiguous fused kernel (or the
+    two-pass pair) over the gathered view.
     """
     interpret = resolve_interpret(interpret)
     g, r, dh = q.shape
@@ -554,37 +488,46 @@ def fused_sparse_decode_attention_paged_kernel(
     nsteps = (mp * ps) // (pair * tk)
     bpp = ppt // pair                     # (pair*tk)-blocks per page
     hpb = heads_per_batch
-    num_pages = k_pool.shape[0]
     kernel = functools.partial(_fused_decode_paged_kernel, scale=scale, l=l,
                                max_score=max_score, sum_rows=sum_rows,
-                               tk=tk, pair=pair, nsteps=nsteps, hpb=hpb,
-                               mp=mp, ps=ps)
+                               tk=tk, pair=pair, nsteps=nsteps, mp=mp)
 
-    def pool_idx(gi, kj, pt):             # newest-first view block -> pool
-        bt = nsteps - 1 - kj
+    def view_block(kj):                   # newest-first attention block
+        return nsteps - 1 - jnp.maximum(kj - mp, 0)
+
+    def pool_idx(gi, kj, pt):
+        bt = view_block(kj)
         return (pt[gi // hpb, bt // bpp], gi % hpb, bt % bpp, 0)
 
+    def hist_page(gi, kj, pt):            # page kj of the slot (phase 1)
+        return (pt[gi // hpb, jnp.minimum(kj, mp - 1)], gi % hpb, 0, 0)
+
+    block = pair * tk
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(g, nsteps),
+        grid=(g, mp + nsteps),
         in_specs=[
             pl.BlockSpec((1, r, dh), lambda gi, kj, pt: (gi, 0, 0)),
-            pl.BlockSpec((1, 1, pair * tk, dh), pool_idx),
-            pl.BlockSpec((1, 1, pair * tk, dh), pool_idx),
+            pl.BlockSpec((1, 1, block, dh), pool_idx),
+            pl.BlockSpec((1, 1, block, dh), pool_idx),
             pl.BlockSpec((1, r, m), lambda gi, kj, pt: (gi, 0, 0)),
-            pl.BlockSpec((num_pages, 1, ps, m),
-                         lambda gi, kj, pt: (0, gi % hpb, 0, 0)),
-            pl.BlockSpec((1, mp * ps), lambda gi, kj, pt: (gi // hpb, 0)),
+            pl.BlockSpec((1, 1, ps, m), hist_page),
+            pl.BlockSpec((1, 1, ps), lambda gi, kj, pt: (
+                gi // hpb, 0, jnp.minimum(kj, mp - 1))),
+            pl.BlockSpec((1, 1, block, m), pool_idx),
+            pl.BlockSpec((1, 1, block), lambda gi, kj, pt: (
+                gi // hpb, 0, view_block(kj))),
         ],
         out_specs=pl.BlockSpec((1, r, dh), lambda gi, kj, pt: (gi, 0, 0)),
-        scratch_shapes=_decode_scratch(r, r_out, dh),
+        scratch_shapes=_decode_scratch(r, r_out, dh) + [
+            vmem((r_out, max_score + 1), jnp.int32)],
     )
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g, r, dh), q.dtype),
         interpret=interpret,
     )(page_table.astype(jnp.int32), q, k_pool, v_pool, codes_q, codes_pool,
-      kv_valid)
+      lane_row(kv_valid), codes_pool, lane_row(kv_valid))
 
 
 def _dense_decode_paged_kernel(pt_ref, q_ref, k_ref, v_ref, valid_ref, o_ref,
@@ -598,29 +541,13 @@ def _dense_decode_paged_kernel(pt_ref, q_ref, k_ref, v_ref, valid_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    valid = valid_ref[0] != 0             # (Tk,)
+    valid = valid_ref[0] != 0             # (1, Tk)
 
     @pl.when(jnp.any(valid))
     def _block():
-        q = q_ref[0].astype(jnp.float32)              # (R, dh)
-        k = k_ref[0, 0].astype(jnp.float32)           # (Tk, dh)
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        logits = jnp.where(valid[None, :], logits, -jnp.inf)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1))
-        finite = m_new > -jnp.inf
-        m_safe = jnp.where(finite, m_new, 0.0)
-        alpha = jnp.where(finite, jnp.exp(m_prev - m_safe), 1.0)
-        p = jnp.where(finite[:, None], jnp.exp(logits - m_safe[:, None]), 0.0)
-        p = jnp.where(valid[None, :], p, 0.0)
-        v = v_ref[0, 0].astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
-        m_ref[:, 0] = m_new
+        _online_softmax_tile(lambda: q_ref[0], lambda: k_ref[0, 0],
+                             lambda: v_ref[0, 0], valid, m_ref, l_ref,
+                             acc_ref, scale=scale)
 
     @pl.when(kj == nkt - 1)
     def _finish():
@@ -661,7 +588,7 @@ def dense_decode_attention_paged_kernel(
             pl.BlockSpec((1, r, dh), lambda gi, kj, pt: (gi, 0, 0)),
             pl.BlockSpec((1, 1, tk, dh), pool_idx),
             pl.BlockSpec((1, 1, tk, dh), pool_idx),
-            pl.BlockSpec((1, tk), lambda gi, kj, pt: (gi // hpb, kj)),
+            pl.BlockSpec((1, 1, tk), lambda gi, kj, pt: (gi // hpb, 0, kj)),
         ],
         out_specs=pl.BlockSpec((1, r, dh), lambda gi, kj, pt: (gi, 0, 0)),
         scratch_shapes=[
@@ -674,4 +601,4 @@ def dense_decode_attention_paged_kernel(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g, r, dh), q.dtype),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), q, k_pool, v_pool, kv_valid)
+    )(page_table.astype(jnp.int32), q, k_pool, v_pool, lane_row(kv_valid))

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax-importing module: jax locks the
-# device count on first init.  Everything below is ordinary.
 """Multi-pod dry-run (deliverable e): for every (arch x input-shape) cell,
 ``jit(step).lower(...).compile()`` against the production mesh — 16x16
 single-pod and 2x16x16 multi-pod — with ShapeDtypeStruct inputs (no device
@@ -13,8 +9,8 @@ Usage:
   python -m repro.launch.dryrun --all --mesh both --out results/dryrun
 """
 import argparse
-import dataclasses
 import json
+import os
 import pathlib
 import sys
 import time
@@ -35,17 +31,9 @@ from repro.sharding import axis_rules, rules_for_mesh
 from repro.train import state as S
 
 
-def apply_variant(cfg: ModelConfig, variant: str) -> ModelConfig:
-    if variant == "spt":
-        return cfg
-    if variant == "lora":
-        return cfg.with_spt(sparse_mha=False, routed_ffn=False)
-    if variant == "full":
-        import dataclasses as dc
-        from repro.core.lora import LoRAConfig
-        return cfg.with_spt(sparse_mha=False, routed_ffn=False,
-                            lora=LoRAConfig(enabled=False))
-    raise ValueError(variant)
+# The pod the dry-run models on virtual CPU devices: its roofline terms
+# use this chip's published peaks (launch/roofline.py PEAKS).
+TARGET_KIND = "TPU v5 lite"
 
 
 def lower_cell(cfg: ModelConfig, shape: ShapeSpec, mesh,
@@ -123,7 +111,7 @@ def exact_roofline(cfg: ModelConfig, shape: ShapeSpec, mesh,
         for u in (1, 2):
             compiled = lower_cell(_unit_config(acfg, u), shape, mesh,
                                   loss_chunk=2048).compile()
-            rl[u] = roofline.analyze(compiled)
+            rl[u] = roofline.analyze(compiled, TARGET_KIND)
     per_unit = {
         "flops": rl[2].flops - rl[1].flops,
         "hbm_bytes": rl[2].hbm_bytes - rl[1].hbm_bytes,
@@ -136,7 +124,8 @@ def exact_roofline(cfg: ModelConfig, shape: ShapeSpec, mesh,
                        + per_unit["coll_bytes"] * (units_equiv - 1)),
         coll_by_kind={k: int(v + (rl[2].coll_by_kind.get(k, 0) - v)
                              * (units_equiv - 1))
-                      for k, v in rl[1].coll_by_kind.items()})
+                      for k, v in rl[1].coll_by_kind.items()},
+        device_kind=TARGET_KIND)
     out["per_unit"] = per_unit
     out["one_unit"] = rl[1].to_dict()
     out["roofline_exact"] = total.to_dict()
@@ -172,7 +161,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         return {"arch": arch, "shape": shape_name,
                 "mesh": "multi" if multi_pod else "single",
                 "status": "skipped", "reason": why}
-    cfg = cfg_override or apply_variant(configs.get_config(arch), variant)
+    cfg = cfg_override or configs.apply_variant(configs.get_config(arch),
+                                                variant)
     if spt_overrides:
         cfg = cfg.with_spt(**spt_overrides)
     mesh = make_production_mesh(multi_pod=multi_pod)
@@ -187,7 +177,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         t_lower = time.time() - t0
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
-        rl = roofline.analyze(compiled)
+        rl = roofline.analyze(compiled, TARGET_KIND)
         if shape.kind == "train":
             tokens = shape.global_batch * shape.seq_len
             mf = roofline.model_flops(cfg, tokens)
@@ -292,4 +282,8 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    # 512 virtual CPU devices for the production mesh.  Set here, before
+    # the first backend use, and only when run as a program, so importing
+    # this module changes nothing for its importer.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     sys.exit(main())

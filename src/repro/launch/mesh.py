@@ -11,17 +11,22 @@ module cannot touch jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape, axes):
-    """Arbitrary mesh for tests/examples (e.g. (1, 1) on one CPU device)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+def make_mesh(shape, axes, devices=None):
+    """Arbitrary mesh (e.g. (1, 1) on one CPU device).  Axes are ``Auto``:
+    the model code places activations with sharding constraints and lets
+    the partitioner resolve the rest (JAX's default is ``Explicit``)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def mesh_num_devices(mesh) -> int:

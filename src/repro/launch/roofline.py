@@ -2,9 +2,9 @@
 
 Three terms per (arch x shape x mesh), all in seconds-per-step-per-chip:
 
-    compute    = HLO_FLOPs(per device)      / PEAK_FLOPS
-    memory     = HLO_bytes(per device)      / HBM_BW
-    collective = collective_bytes(per dev)  / ICI_BW
+    compute    = HLO_FLOPs(per device)      / peak FLOP/s
+    memory     = HLO_bytes(per device)      / peak HBM bytes/s
+    collective = collective_bytes(per dev)  / ICI bytes/s per link
 
 HLO_FLOPs / HLO_bytes come from ``compiled.cost_analysis()`` (the SPMD
 partitioned per-device module).  Collective bytes are parsed from the
@@ -12,9 +12,10 @@ optimized HLO text: sum of result-shape bytes of every all-gather /
 all-reduce / reduce-scatter / all-to-all / collective-permute (async
 ``-start`` forms counted once, ``-done`` skipped).
 
-Hardware constants (TPU v5e target): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.  The collective term assumes the payload crosses one
-logical link serially — a deliberate, consistent upper bound; ring
+Peaks come from ``PEAKS``, keyed by ``jax.Device.device_kind``; a kind
+that is not in the table raises instead of borrowing another chip's
+numbers.  The collective term assumes the payload crosses one logical
+link serially — a deliberate, consistent upper bound; ring
 algorithms overlap hops, so treat it as a comparison metric, not a wall
 clock prediction.
 """
@@ -24,9 +25,21 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12      # bf16 / chip
-HBM_BW = 819e9           # bytes/s / chip
-ICI_BW = 50e9            # bytes/s / link
+# Published per-chip peaks, keyed by device_kind.  TPU v5e: Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+# of interconnect over 4 links = 50 GB/s per link.
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peaks of one chip of ``device_kind``; KeyError if unknown."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}"
+                       f"; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,
@@ -130,19 +143,20 @@ class Roofline:
     hbm_bytes: float
     coll_bytes: float
     coll_by_kind: Dict[str, int]
+    device_kind: str
     peak_memory: Optional[float] = None
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / peaks(self.device_kind)["flops"]
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / peaks(self.device_kind)["hbm_bw"]
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / ICI_BW
+        return self.coll_bytes / peaks(self.device_kind)["ici_bw"]
 
     @property
     def bottleneck(self) -> str:
@@ -162,14 +176,14 @@ class Roofline:
             "t_compute": self.t_compute, "t_memory": self.t_memory,
             "t_collective": self.t_collective, "bottleneck": self.bottleneck,
             "t_bound": self.t_bound, "peak_memory": self.peak_memory,
+            "device_kind": self.device_kind,
         }
 
 
-def analyze(compiled, hlo_text: Optional[str] = None,
+def analyze(compiled, device_kind: str, hlo_text: Optional[str] = None,
             traffic_model: bool = True) -> Roofline:
+    peaks(device_kind)              # fail before any work on an unknown chip
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):      # older jax returns [dict]
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()
     hbm = float(hbm_traffic(text)) if traffic_model \
@@ -188,7 +202,8 @@ def analyze(compiled, hlo_text: Optional[str] = None,
         pass
     return Roofline(flops=flops, hbm_bytes=hbm,
                     coll_bytes=float(sum(coll.values())),
-                    coll_by_kind=coll, peak_memory=peak_mem)
+                    coll_by_kind=coll, device_kind=device_kind,
+                    peak_memory=peak_mem)
 
 
 def model_flops(cfg, tokens: int) -> float:

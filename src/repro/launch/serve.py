@@ -19,6 +19,7 @@ import numpy as np
 
 from repro import configs
 from repro.core.params import init_tree
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.serving.engine import Engine, Request
 from repro.sharding import axis_rules, rules_for_mesh
@@ -138,6 +139,7 @@ def main() -> int:
                     help="write the final metrics snapshot (counters/"
                          "gauges/histograms) as JSON here")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke \
         else configs.get_config(args.arch)

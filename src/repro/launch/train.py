@@ -14,6 +14,7 @@ import jax
 
 from repro import configs
 from repro.data.pipeline import DataConfig, synthetic_dataset
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.optim.adamw import OptimizerConfig
 from repro.sharding import axis_rules, rules_for_mesh
@@ -33,11 +34,11 @@ def main() -> int:
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--variant", default="spt", choices=["spt", "lora", "full"])
     args = ap.parse_args()
+    use_compile_cache()
 
-    from repro.launch.dryrun import apply_variant  # reuse variant logic
     cfg = configs.get_smoke(args.arch) if args.smoke \
         else configs.get_config(args.arch)
-    cfg = apply_variant(cfg, args.variant)
+    cfg = configs.apply_variant(cfg, args.variant)
     dp, tp = (int(x) for x in args.mesh.split("x"))
     mesh = make_mesh((dp, tp), ("data", "model"))
     rules = rules_for_mesh(mesh)

@@ -164,8 +164,13 @@ def write_cache(cache: dict, cfg: ModelConfig, p: dict, k: jax.Array,
     new["k"] = cache["k"].at[:, :, slots].set(k.astype(cache["k"].dtype))
     new["v"] = cache["v"].at[:, :, slots].set(v.astype(cache["v"].dtype))
     b = cache["slot_pos"].shape[0]
+    # The barrier keeps this scatter's updates a different instruction from
+    # its indices: for one row from position 0 XLA folds both to the same
+    # iota, and the TPU compiler then aborts while fusing the scatter
+    # (scatter_emitter "operand_indices.size() == 1").
     new["slot_pos"] = cache["slot_pos"].at[:, slots].set(
-        jnp.broadcast_to(pos_k[None], (b, s_new)).astype(jnp.int32))
+        jnp.broadcast_to(jax.lax.optimization_barrier(pos_k)[None],
+                         (b, s_new)).astype(jnp.int32))
     if "codes" in cache:
         codes = pq.assign(k, p["pq"]["codebooks"])        # (B, Hk, S_new, M)
         new["codes"] = cache["codes"].at[:, :, slots].set(
@@ -215,10 +220,12 @@ def attend(p: dict, cfg: ModelConfig, q: jax.Array, k: jax.Array,
     """Full-sequence attention (train/prefill), sparse or dense.
 
     seq_lengths: per-row real lengths (B,) for batched ragged prefill —
-    sparse MHA then selects with each row's exact-length top-L budget
-    (always via the jnp gather path; per-row budgets inside the fused
-    prefill kernel are a follow-on).  Dense attention needs nothing: the
-    causal mask already hides right-pad keys from every real query."""
+    sparse MHA then selects with each row's exact-length top-L budget.
+    The fused prefill kernel has no per-row budgets, so the kernel and
+    masked tiers run it in the masked form (the kernel's compute graph in
+    XLA, no gathered K/V), the jnp tier in the gather form.  Dense
+    attention needs nothing: the causal mask already hides right-pad keys
+    from every real query."""
     scale = cfg.resolved_head_dim ** -0.5
     aux: dict = {}
     if sparse_applicable(cfg):
@@ -227,10 +234,12 @@ def attend(p: dict, cfg: ModelConfig, q: jax.Array, k: jax.Array,
         if impl == "pallas" and kdispatch.kernels_disabled():
             impl = "sparse_jnp"                  # REPRO_DISABLE_KERNELS=1
         if seq_lengths is not None:
-            out, aux = sa.sparse_mha(q, k, v, p["pq"]["codebooks"], scfg,
-                                     scale, causal=causal, window=window,
-                                     q_offset=q_offset,
-                                     seq_lengths=seq_lengths)
+            ragged = (sa.sparse_mha_masked
+                      if impl in ("pallas", "sparse_masked")
+                      else sa.sparse_mha)
+            out, aux = ragged(q, k, v, p["pq"]["codebooks"], scfg, scale,
+                              causal=causal, window=window,
+                              q_offset=q_offset, seq_lengths=seq_lengths)
         elif impl == "pallas":
             from repro.kernels.sparse_attention import ops as sa_ops
             out, aux = sa_ops.sparse_mha(q, k, v, p["pq"]["codebooks"], scfg,

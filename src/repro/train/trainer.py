@@ -12,6 +12,7 @@ from typing import Any, Callable, Dict, Iterator, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from repro.configs.base import ModelConfig
 from repro.launch import steps as steps_lib
@@ -46,12 +47,17 @@ class Trainer:
 
         step_fn = steps_lib.build_train_step(cfg, ocfg,
                                              loss_chunk=tcfg.loss_chunk)
+        # On a mesh, the state lives where state_specs puts it (each
+        # device holds its share) and stays there across steps; batches
+        # are placed by batch_specs as they arrive.
+        self._state_sh = None
+        out_sh = None
         if mesh is not None and rules is not None:
-            from repro.configs.shapes import input_specs  # noqa: F401
-            st = S.state_specs(cfg, rules)
-            self._step = jax.jit(step_fn, donate_argnums=(0,))
-        else:
-            self._step = jax.jit(step_fn, donate_argnums=(0,))
+            self._state_sh = steps_lib._map_specs(
+                mesh, S.state_specs(cfg, rules))
+            out_sh = (self._state_sh, steps_lib._ns(mesh, PartitionSpec()))
+        self._step = jax.jit(step_fn, out_shardings=out_sh,
+                             donate_argnums=(0,))
 
         # resume-or-init
         start = None
@@ -59,9 +65,15 @@ class Trainer:
             start = checkpoint.latest_step(tcfg.ckpt_dir)
         if start is not None:
             self.state = checkpoint.restore(tcfg.ckpt_dir, start)
+            if self._state_sh is not None:
+                self.state = jax.device_put(self.state, self._state_sh)
             self.start_step = int(start)
         else:
-            self.state = S.init_state(cfg, jax.random.PRNGKey(seed))
+            init = S.init_state
+            if self._state_sh is not None:    # born sharded, never whole
+                init = jax.jit(init, static_argnums=0,
+                               out_shardings=self._state_sh)
+            self.state = init(cfg, jax.random.PRNGKey(seed))
             self.start_step = 0
 
         # preemption-safe: SIGTERM triggers an emergency checkpoint
@@ -78,13 +90,21 @@ class Trainer:
             checkpoint.save(self.state, step, self.tcfg.ckpt_dir,
                             keep=self.tcfg.keep_checkpoints)
 
+    def _place(self, batch: Dict[str, np.ndarray]) -> Dict[str, jax.Array]:
+        if self._state_sh is None:
+            return {k: jnp.asarray(v) for k, v in batch.items()}
+        specs = steps_lib.batch_specs(
+            self.cfg, {k: jax.ShapeDtypeStruct(np.shape(v), np.asarray(v).dtype)
+                       for k, v in batch.items()}, self.rules)
+        return jax.device_put(batch, steps_lib._map_specs(self.mesh, specs))
+
     def run(self, data: Iterator[Dict[str, np.ndarray]],
             step_hook: Optional[Callable[[int, dict], None]] = None) -> dict:
         step = self.start_step
         for batch in data:
             if step >= self.tcfg.total_steps or self._stop:
                 break
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            batch = self._place(batch)
             self.monitor.start()
             self.state, metrics = self._step(self.state, batch)
             metrics = {k: float(v) for k, v in metrics.items()}

@@ -173,6 +173,20 @@ def _toy_pallas(block_shape, array_shape, dtype=jnp.float32,
     return jax.make_jaxpr(fn)(jax.ShapeDtypeStruct(array_shape, dtype))
 
 
+def _toy_pallas3(block_shape, array_shape):
+    """3-D twin of ``_toy_pallas`` (grid over the leading two dims)."""
+    def kernel(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+
+    grid = tuple(-(-a // b) for a, b in zip(array_shape[:2], block_shape))
+    spec = pl.BlockSpec(block_shape, lambda i, j: (i, j, 0))
+    fn = pl.pallas_call(
+        kernel, grid=grid, in_specs=[spec], out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(array_shape, jnp.float32),
+        interpret=True)
+    return jax.make_jaxpr(fn)(jax.ShapeDtypeStruct(array_shape, jnp.float32))
+
+
 def test_accum_dtype_rule_fires_on_bf16_dot():
     def kernel(a_ref, b_ref, o_ref):
         o_ref[...] = jnp.dot(a_ref[...], b_ref[...])   # bf16 accumulate
@@ -196,11 +210,27 @@ def test_vmem_budget_rule_fires_on_oversized_block():
 
 
 def test_tile_divisibility_rule_fires_on_ragged_block():
-    calls = _collect(_toy_pallas((32, 64), (48, 64)))    # 48 % 32 != 0
+    """The TPU rule: a (1, tk) block over a (B, S) validity row — the
+    layout Mosaic refused in every decode kernel — fires; the (B, 1, S)
+    layout with (1, 1, tk) blocks, which the kernels now use, does not."""
+    calls = _collect(_toy_pallas((1, 512), (4, 2048)))
     assert rules(pa.tile_divisibility_violations(calls, "toy")) \
         == ["pallas.tile-divisibility"]
-    ok = _collect(_toy_pallas((16, 64), (48, 64)))
+    ok = _collect(_toy_pallas3((1, 1, 512), (4, 1, 2048)))
     assert pa.tile_divisibility_violations(ok, "toy") == []
+
+
+@pytest.mark.parametrize("block,array,fires", [
+    ((32, 64), (48, 64), False),      # 32 % 8 == 0, last dim is whole
+    ((8, 128), (16, 256), False),     # aligned on both
+    ((4, 128), (16, 256), True),      # sublane dim 4 is not a multiple of 8
+    ((8, 64), (16, 256), True),       # lane dim 64 is not a multiple of 128
+    ((16, 256), (16, 256), False),    # whole array
+])
+def test_tile_rule_is_tpu_8_128_or_full(block, array, fires):
+    calls = _collect(_toy_pallas(block, array))
+    got = pa.tile_divisibility_violations(calls, "toy")
+    assert rules(got) == (["pallas.tile-divisibility"] if fires else [])
 
 
 def test_scalar_prefetch_contract():
@@ -232,26 +262,7 @@ def test_audit_calls_flags_vacuous_entry():
 
 
 def _collect(jaxpr):
-    out = []
-    for eqn in ja.iter_eqns(jaxpr):
-        if eqn.primitive.name != "pallas_call":
-            continue
-        gm = eqn.params["grid_mapping"]
-        blocks = tuple(
-            pa.BlockInfo(block_shape=tuple(bm.block_shape),
-                         array_shape=tuple(bm.array_shape_dtype.shape),
-                         dtype=jnp.dtype(bm.array_shape_dtype.dtype).name,
-                         itemsize=jnp.dtype(
-                             bm.array_shape_dtype.dtype).itemsize,
-                         any_space=False)
-            for bm in gm.block_mappings)
-        out.append(pa.PallasCallInfo(
-            name=str(eqn.params.get("name_and_src_info", "?")),
-            grid=tuple(int(g) for g in gm.grid),
-            num_index_operands=int(gm.num_index_operands),
-            num_scratch_operands=int(gm.num_scratch_operands),
-            blocks=blocks, scratch_bytes=0))
-    return out
+    return pa.calls_in_jaxpr(jaxpr)
 
 
 # ------------------------------------------------------------ trace guard

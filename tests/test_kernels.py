@@ -120,6 +120,35 @@ def test_fused_sparse_attention_backward_matches_ref():
                                    rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("granularity", ["qhead", "kvgroup"])
+@pytest.mark.parametrize("ragged", [False, True], ids=["full", "ragged"])
+def test_masked_form_matches_gather_form(granularity, ragged):
+    """The masked form (the fused op's backward, and ragged prefill on the
+    kernel tier) selects the gather form's top-L set — per-row budgets and
+    kvgroup selection included — so outputs and gradients agree."""
+    pcfg, cb = _cb(32)
+    scfg = sa.SparseAttentionConfig(pq=pcfg, top_fraction=0.25, min_l=4,
+                                    chunk_q=16,
+                                    select_granularity=granularity)
+    q = jax.random.normal(jax.random.PRNGKey(8), (2, 4, 32, 32))
+    k = jax.random.normal(jax.random.PRNGKey(9), (2, 2, 32, 32))
+    v = jax.random.normal(jax.random.PRNGKey(10), (2, 2, 32, 32))
+    lens = jnp.asarray([32, 19], jnp.int32) if ragged else None
+
+    def loss(form):
+        return lambda q, k, v: jnp.sum(form(
+            q, k, v, cb, scfg, 32 ** -0.5, seq_lengths=lens)[0] ** 2)
+
+    for a, b in zip(jax.value_and_grad(loss(sa.sparse_mha_masked),
+                                       argnums=(0, 1, 2))(q, k, v),
+                    jax.value_and_grad(loss(sa.sparse_mha),
+                                       argnums=(0, 1, 2))(q, k, v)):
+        for x, y in zip(jax.tree_util.tree_leaves(a),
+                        jax.tree_util.tree_leaves(b)):
+            np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                       rtol=1e-4, atol=1e-4)
+
+
 # ------------------------------------------------------------ routed ffn
 @pytest.mark.parametrize("bsz,s,d,dff,g,act_g,gated,act", [
     (2, 16, 32, 64, 4, 2, False, "relu"),

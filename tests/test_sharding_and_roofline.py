@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec
 
 from repro import configs
@@ -85,12 +86,21 @@ ENTRY %main (p0: f32[128,64], p1: f32[64,32]) -> f32[128,32] {
 
 def test_roofline_terms_and_bottleneck():
     rl = roofline.Roofline(flops=197e12, hbm_bytes=819e9 * 2,
-                           coll_bytes=50e9 * 0.5, coll_by_kind={})
+                           coll_bytes=50e9 * 0.5, coll_by_kind={},
+                           device_kind="TPU v5 lite")
     assert abs(rl.t_compute - 1.0) < 1e-9
     assert abs(rl.t_memory - 2.0) < 1e-9
     assert abs(rl.t_collective - 0.5) < 1e-9
     assert rl.bottleneck == "memory"
     assert rl.t_bound == 2.0
+
+
+def test_peaks_keyed_by_device_kind_and_unknown_kind_raises():
+    assert roofline.peaks("TPU v5 lite")["flops"] == 197e12
+    rl = roofline.Roofline(flops=1.0, hbm_bytes=1.0, coll_bytes=0.0,
+                           coll_by_kind={}, device_kind="cpu")
+    with pytest.raises(KeyError, match="cpu"):
+        rl.t_compute
 
 
 def test_model_flops_moe_counts_active_only():

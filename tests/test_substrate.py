@@ -149,7 +149,7 @@ def test_adapter_preserves_function_at_identity_settings():
     """Dense model == adapted SPT model when sparsity is a no-op:
     top_fraction=1 (all keys kept) and active_groups == groups (all blocks
     active), LoRA zero-init.  This is the paper's Model Adapter contract."""
-    from repro.launch.dryrun import apply_variant
+    from repro.configs import apply_variant
     from repro.models import transformer
     base = configs.get_smoke("h2o-danube-1.8b")
     base = dataclasses.replace(base, window=None)
@@ -170,3 +170,26 @@ def test_adapter_preserves_function_at_identity_settings():
     np.testing.assert_allclose(np.asarray(h_dense, np.float32),
                                np.asarray(h_spt, np.float32),
                                rtol=5e-2, atol=5e-2)
+
+
+def test_compile_cache_env_wins_else_fixed_ignored_path(monkeypatch):
+    """Entry points keep the persistent compile cache where
+    JAX_COMPILATION_CACHE_DIR says, setting nothing in code; without it,
+    at one fixed path in the checkout that .gitignore lists."""
+    import pathlib
+
+    from repro.launch import compile_cache as cc
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "cache-from-env")
+        assert cc.use_compile_cache() == "cache-from-env"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert cc.use_compile_cache() == str(cc.CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(cc.CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert cc.CACHE_DIR.parent == root
+    assert f"{cc.CACHE_DIR.name}/" in (root / ".gitignore").read_text().split()

@@ -56,6 +56,38 @@ def test_resume_continues_from_checkpoint(tmp_path):
     assert rep["final_step"] == 20
 
 
+def test_trainer_places_state_by_state_specs():
+    """On a mesh, every state leaf carries the NamedSharding of
+    state_specs after init and after a step: a change that dropped the
+    placement would leave the state on one device on a multi-chip mesh."""
+    from jax.sharding import NamedSharding
+
+    from repro.launch.mesh import make_mesh
+    from repro.sharding import axis_rules, rules_for_mesh
+    from repro.train.state import state_specs
+
+    cfg = _tiny_cfg()
+    mesh = make_mesh((1, 1), ("data", "model"))
+    rules = rules_for_mesh(mesh)
+    want = jax.tree_util.tree_map(
+        lambda spec: NamedSharding(mesh, spec), state_specs(cfg, rules),
+        is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
+
+    def check(state):
+        got = [a.sharding for a in jax.tree_util.tree_leaves(state)]
+        assert got == jax.tree_util.tree_leaves(want)
+
+    with mesh, axis_rules(rules):
+        t = Trainer(cfg, OptimizerConfig(total_steps=1),
+                    TrainerConfig(total_steps=1), mesh=mesh, rules=rules)
+        check(t.state)
+        rep = t.run(synthetic_dataset(
+            DataConfig(vocab_size=cfg.vocab_size, seq_len=16,
+                       global_batch=2), steps=2))
+    assert rep["final_step"] == 1
+    check(t.state)
+
+
 def test_serve_after_training_deterministic():
     cfg = _tiny_cfg()
     params = init_tree(model_defs(cfg), jax.random.PRNGKey(0))
