@@ -23,7 +23,7 @@ Shapes: x (B, S, d); choice/gate (B, S, K); plan.index (B, G, C).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +53,25 @@ def capacity(tokens_per_seq: int, num_groups: int, topk: int,
     c = int(tokens_per_seq * topk * capacity_factor / num_groups) + 1
     c = -(-c // pad) * pad
     return min(c, max(pad, -(-tokens_per_seq * topk // pad) * pad))
+
+
+def slot_counts(batch: int, tokens_per_seq: int, layers: int,
+                num_groups: int, topk: int, capacity_factor: float,
+                dropped: float, pad: int = 8) -> Dict[str, float]:
+    """Host-side counts of one step's dispatch over ``layers`` routed
+    layers, from static shapes and ``dropped`` (the dropped fractions of
+    the layers, summed):
+
+      routed_pairs  (token, choice) pairs routed;
+      routed_kept   pairs that found a slot under ``capacity``;
+      routed_slots  slots the (B, G, C) buffers hold.
+
+    kept / slots is how full the grouped kernel's slots run."""
+    pairs = batch * tokens_per_seq * topk * layers
+    cap = capacity(tokens_per_seq, num_groups, topk, capacity_factor, pad)
+    return {"routed_pairs": float(pairs),
+            "routed_kept": pairs * (1.0 - float(dropped) / layers),
+            "routed_slots": float(batch * layers * num_groups * cap)}
 
 
 def capacity_dyn(tokens_per_seq: jax.Array, num_groups: int, topk: int,

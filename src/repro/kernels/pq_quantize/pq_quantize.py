@@ -55,5 +55,6 @@ def pq_assign_kernel(x: jax.Array, codebooks: jax.Array, *, tile_n: int = 256,
         ],
         out_specs=pl.BlockSpec((1, tn, m), lambda gi, i: (gi, i, 0)),
         out_shape=jax.ShapeDtypeStruct((g, n, m), jnp.int32),
+        name="pq_assign",
         interpret=interpret,
     )(x, codebooks)

@@ -307,6 +307,7 @@ def grouped_ffn_kernel(x: jax.Array, index: jax.Array, w_inner: jax.Array,
     y = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, g, cp_, d), x.dtype),
+        name="routed_ffn_grouped",
         interpret=interpret)(index.astype(jnp.int32), *inputs)
     return y[:, :, :c] if c_pad else y
 
@@ -459,6 +460,7 @@ def decode_ffn_kernel(x: jax.Array, choice: jax.Array, gate: jax.Array,
     y = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, d), x.dtype),
+        name="routed_ffn_decode",
         interpret=interpret)(choice.astype(jnp.int32),
                              gate.astype(jnp.float32), *inputs)
     return y.reshape(b, d)

@@ -109,6 +109,7 @@ def sparse_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
             vmem((tq, dh), jnp.float32),
             vmem((tq, 1), jnp.int32),
         ],
+        name="sparse_attention_fwd",
         interpret=interpret,
     )(q, k, v, codes_q, codes_k, thresholds)
 
@@ -259,6 +260,7 @@ def sparse_decode_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
             vmem((r, dh), jnp.float32),
             vmem((r_out, 1), jnp.int32),
         ],
+        name="sparse_attention_decode",
         interpret=interpret,
     )(q, k, v, codes_q, codes_k, thresholds, lane_row(kv_valid))
 
@@ -397,6 +399,7 @@ def fused_sparse_decode_attention_kernel(
         out_specs=pl.BlockSpec((1, r, dh), lambda gi, kj: (gi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((g, r, dh), q.dtype),
         scratch_shapes=_decode_scratch(r, r_out, dh),
+        name="sparse_attention_decode_fused",
         interpret=interpret,
     )(q, k, v, codes_q, codes_k, lane_row(kv_valid))
 
@@ -525,6 +528,7 @@ def fused_sparse_decode_attention_paged_kernel(
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g, r, dh), q.dtype),
+        name="sparse_attention_decode_paged",
         interpret=interpret,
     )(page_table.astype(jnp.int32), q, k_pool, v_pool, codes_q, codes_pool,
       lane_row(kv_valid), codes_pool, lane_row(kv_valid))
@@ -600,5 +604,6 @@ def dense_decode_attention_paged_kernel(
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g, r, dh), q.dtype),
+        name="dense_attention_decode_paged",
         interpret=interpret,
     )(page_table.astype(jnp.int32), q, k_pool, v_pool, lane_row(kv_valid))

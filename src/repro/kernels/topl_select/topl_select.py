@@ -131,6 +131,7 @@ def topl_thresholds_kernel(codes_q: jax.Array, codes_k: jax.Array, *,
         out_specs=pl.BlockSpec((1, tq, 2), lambda gi, qi, ki: (gi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((g, nq, 2), jnp.int32),
         scratch_shapes=[vmem((tq, max_score + 1), jnp.int32)],
+        name="topl_thresholds",
         interpret=interpret,
     )(codes_q, codes_k)
 
@@ -250,5 +251,6 @@ def decode_topl_thresholds_kernel(codes_q: jax.Array, codes_k: jax.Array,
         out_specs=pl.BlockSpec((1, r_out, 2), lambda gi, ki: (gi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((g, r_out, 2), jnp.int32),
         scratch_shapes=[vmem((r_out, max_score + 1), jnp.int32)],
+        name="topl_thresholds_decode",
         interpret=interpret,
     )(codes_q, codes_k, lane_row(kv_valid))

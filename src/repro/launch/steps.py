@@ -26,7 +26,7 @@ from repro.train.loss import lm_cross_entropy
 # ------------------------------------------------------------- steps
 def build_train_step(cfg: ModelConfig, ocfg: OptimizerConfig,
                      loss_chunk: int = 512) -> Callable:
-    def train_step(state: dict, batch: Dict[str, jax.Array]):
+    def spt_train_step(state: dict, batch: Dict[str, jax.Array]):
         def loss_fn(train):
             params = P.combine(train, state["frozen"])
             hidden, aux = S.model_hidden(params, cfg, batch, remat=True)
@@ -50,7 +50,7 @@ def build_train_step(cfg: ModelConfig, ocfg: OptimizerConfig,
         metrics = {"loss": loss, **metrics, **om}
         return new_state, metrics
 
-    return train_step
+    return spt_train_step
 
 
 def build_prefill_step(cfg: ModelConfig, max_len: int) -> Callable:

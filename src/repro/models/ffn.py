@@ -43,6 +43,23 @@ def routed_applicable(cfg: ModelConfig) -> bool:
             and cfg.d_ff % cfg.spt.ffn_groups == 0)
 
 
+def slot_counts(cfg: ModelConfig, batch: int, seq: int,
+                dropped: float) -> Optional[Dict[str, float]]:
+    """``dispatch.slot_counts`` of one train step of (batch, seq) tokens
+    through the layers of a decoder-only stack whose FFN routes through
+    the capacity dispatch, which the step's ``dropped`` is summed over.
+    None where no layer does (dense or MoE FFNs, the dense routed oracle,
+    the audio encoder-decoder)."""
+    if (cfg.family == "audio" or cfg.num_experts > 0
+            or not routed_applicable(cfg) or cfg.spt.ffn_impl == "dense"):
+        return None
+    layers = sum(kind != "ssd" for kind in cfg.layer_types())
+    rc = _routed_cfg(cfg)
+    return dispatch.slot_counts(batch, seq, layers, rc.num_groups,
+                                rc.active_groups, rc.capacity_factor,
+                                dropped, pad=rc.capacity_pad)
+
+
 def ffn_defs(cfg: ModelConfig) -> dict:
     lc = cfg.spt.lora
     if routed_applicable(cfg):

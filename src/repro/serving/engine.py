@@ -524,11 +524,11 @@ class Engine:
         self._prefill_one: Optional[Callable] = None
         self._chunk_cache: Dict[Any, Callable] = {}
         if self._paged:
-            def _ws(caches, rows, slots, page_table):
+            def spt_kv_write(caches, rows, slots, page_table):
                 return transformer.write_slot_caches_paged_rows(
                     caches, rows, slots, page_table, cfg)
-            self._write_rows = (jax.jit(_ws, donate_argnums=(0,))
-                                if jit else _ws)
+            self._write_rows = (jax.jit(spt_kv_write, donate_argnums=(0,))
+                                if jit else spt_kv_write)
             self._alloc_rows = (
                 jax.jit(kvp.alloc_rows_pages, donate_argnums=(0, 1))
                 if jit else kvp.alloc_rows_pages)
@@ -595,13 +595,14 @@ class Engine:
             cfg, max_len = self.cfg, self.max_len
             tel_on = self._tel_counters
 
-            def fn(params, batch, lengths):
+            def spt_prefill_ragged(params, batch, lengths):
                 return transformer.lm_prefill_ragged(
                     params, cfg, batch, lengths, max_len,
                     return_counters=tel_on)
             # no-donate: ragged prefill also inits its cache rows in-jit;
             # tokens/lengths are read-only and alias no output.
-            self._prefill_one = jax.jit(fn) if self._use_jit else fn
+            self._prefill_one = (jax.jit(spt_prefill_ragged)
+                                 if self._use_jit else spt_prefill_ragged)
         return self._prefill_one
 
     def _prefill_group(self, group: Sequence["_QItem"]):
@@ -718,8 +719,9 @@ class Engine:
             return jnp.where(temps > 0.0, sampled,
                              jnp.argmax(lg, axis=-1).astype(jnp.int32))
 
-        def chunk(params, caches, page_table, astate, tok, pos, active, n,
-                  limit, buf, keys, temps, topks, topps):
+        def spt_decode_chunk(params, caches, page_table, astate, tok, pos,
+                             active, n, limit, buf, keys, temps, topks,
+                             topps):
             def cond(c):
                 return (c[0] < chunk_steps) & jnp.any(c[6])
 
@@ -831,7 +833,10 @@ class Engine:
             return res + ((out[9],) if tel_on else ())
 
         if self._use_jit:
-            chunk = jax.jit(chunk, donate_argnums=CHUNK_DONATE_ARGNUMS)
+            chunk = jax.jit(spt_decode_chunk,
+                            donate_argnums=CHUNK_DONATE_ARGNUMS)
+        else:
+            chunk = spt_decode_chunk
         self._chunk_cache[key] = chunk
         return chunk
 
@@ -1198,33 +1203,34 @@ class Engine:
         frontend = cfg.frontend_tokens if cfg.frontend else 0
         ps = self.page_size
         t0 = time.perf_counter()
-        rows, logits, bpb, tel = self._prefill_group(group)
-        slot_vec = np.full(bpb, -1, np.int32)   # -1 rows: dummies, drop
-        assigned: List[int] = []
-        for i, it in enumerate(group):
-            b = next(j for j, s in enumerate(st.slot_item) if s is None)
-            st.slot_item[b] = it
-            assigned.append(b)
-            slot_vec[i] = b
-        if self._paged:
-            npages = np.zeros(bpb, np.int32)
+        with jax.profiler.TraceAnnotation("spt.serve.prefill_batch"):
+            rows, logits, bpb, tel = self._prefill_group(group)
+            slot_vec = np.full(bpb, -1, np.int32)   # -1 rows: dummies, drop
+            assigned: List[int] = []
             for i, it in enumerate(group):
-                ws = self._pages_ws(it.req)
-                st.reserved += ws
-                st.slot_ws[assigned[i]] = ws
-                npages[i] = kvp.num_pages(
-                    frontend + len(it.prefill_tokens()), ps)
-            st.astate, st.page_table = self._alloc_rows(
-                st.astate, st.page_table, jnp.asarray(slot_vec),
-                jnp.asarray(npages))
-            st.caches = self._write_rows(st.caches, rows,
-                                         jnp.asarray(slot_vec),
-                                         st.page_table)
-        else:
-            st.caches = self._write_rows(st.caches, rows,
-                                         jnp.asarray(slot_vec))
-        logits = jax.block_until_ready(logits)
-        jax.block_until_ready(st.caches)
+                b = next(j for j, s in enumerate(st.slot_item) if s is None)
+                st.slot_item[b] = it
+                assigned.append(b)
+                slot_vec[i] = b
+            if self._paged:
+                npages = np.zeros(bpb, np.int32)
+                for i, it in enumerate(group):
+                    ws = self._pages_ws(it.req)
+                    st.reserved += ws
+                    st.slot_ws[assigned[i]] = ws
+                    npages[i] = kvp.num_pages(
+                        frontend + len(it.prefill_tokens()), ps)
+                st.astate, st.page_table = self._alloc_rows(
+                    st.astate, st.page_table, jnp.asarray(slot_vec),
+                    jnp.asarray(npages))
+                st.caches = self._write_rows(st.caches, rows,
+                                             jnp.asarray(slot_vec),
+                                             st.page_table)
+            else:
+                st.caches = self._write_rows(st.caches, rows,
+                                             jnp.asarray(slot_vec))
+            logits = jax.block_until_ready(logits)
+            jax.block_until_ready(st.caches)
         now_wall = time.perf_counter()
         rec = self.recorder
         if rec is not None and tel is not None:
@@ -1327,13 +1333,14 @@ class Engine:
         # read between the call and its reassignment below
         was_active = st.active.copy()
         t0 = time.perf_counter()
-        out = chunk_fn(self.params, st.caches, st.page_table, st.astate,
-                       jnp.asarray(st.tok), jnp.asarray(st.pos),
-                       jnp.asarray(st.active), jnp.asarray(st.n_gen),
-                       jnp.asarray(st.limit), jnp.asarray(st.buf),
-                       jnp.asarray(st.keys), jnp.asarray(st.temps),
-                       jnp.asarray(st.topks), jnp.asarray(st.topps))
-        out = jax.block_until_ready(out)
+        with jax.profiler.TraceAnnotation("spt.serve.decode_chunk"):
+            out = chunk_fn(self.params, st.caches, st.page_table, st.astate,
+                           jnp.asarray(st.tok), jnp.asarray(st.pos),
+                           jnp.asarray(st.active), jnp.asarray(st.n_gen),
+                           jnp.asarray(st.limit), jnp.asarray(st.buf),
+                           jnp.asarray(st.keys), jnp.asarray(st.temps),
+                           jnp.asarray(st.topks), jnp.asarray(st.topps))
+            out = jax.block_until_ready(out)
         (st.caches, st.page_table, st.astate, tok_d, pos_d, act_d, n_d,
          buf_d, steps) = out[:9]
         t1 = time.perf_counter()
@@ -1341,7 +1348,8 @@ class Engine:
         rec = self.recorder
         if rec is not None and self._tel_counters:
             # ONE host fetch per chunk, inside the already-synced region
-            rec.drain_counters(jax.device_get(out[9]))
+            with jax.profiler.TraceAnnotation("spt.serve.drain"):
+                rec.drain_counters(jax.device_get(out[9]))
             t2 = time.perf_counter()
             rec.span("drain", t1, t2, st.iteration)
         if rec is not None:
@@ -1441,7 +1449,8 @@ class Engine:
         self._shed_expired(now)
         tp0 = time.perf_counter()
         pre_before = st.stats.preemptions
-        self._pressure_preempt(now)
+        with jax.profiler.TraceAnnotation("spt.serve.pressure_preempt"):
+            self._pressure_preempt(now)
         if rec is not None and st.stats.preemptions > pre_before:
             rec.span("pressure_preempt", tp0, time.perf_counter(),
                      st.iteration,
@@ -1449,13 +1458,14 @@ class Engine:
         ta0 = time.perf_counter()
         admitted_before = st.stats.admitted
         stalled_seen: set = set()
-        while True:
-            group = self._form_group(stalled_seen)
-            if not group:
-                break
-            self._admit(group)
-            if self.prefill_decode_ratio > 0 and st.active.any():
-                break           # overlap: hand control back to decode
+        with jax.profiler.TraceAnnotation("spt.serve.admission"):
+            while True:
+                group = self._form_group(stalled_seen)
+                if not group:
+                    break
+                self._admit(group)
+                if self.prefill_decode_ratio > 0 and st.active.any():
+                    break       # overlap: hand control back to decode
         if rec is not None and st.stats.admitted > admitted_before:
             rec.span("admission", ta0, time.perf_counter(), st.iteration,
                      admitted=st.stats.admitted - admitted_before,

@@ -16,6 +16,11 @@ Three layers (ISSUE 9 / ROADMAP "observability"):
     spans and gauges, and aggregation of the jit-pure device counters
     (tel_* trees) the engine drains once per scheduling iteration.
 
+The recorder's spans run on ``time.perf_counter``.  At the same sites
+the engine also opens ``jax.profiler.TraceAnnotation("spt.serve.<phase>")``
+in every telemetry mode, so a profiler trace shows each phase on the
+device trace's clock.
+
 This module is engine-agnostic: it never imports ``serving.engine`` and
 holds no jax arrays — the engine hands it host data (floats / numpy)
 exactly once per scheduling iteration, so nothing here can add a device

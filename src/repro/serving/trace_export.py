@@ -1,5 +1,5 @@
-"""Trace export: Chrome-trace/Perfetto ``trace.json`` and JSONL event
-logs from a ``TelemetryRecorder``.
+"""Trace export: Chrome-trace/Perfetto ``trace.json`` from a
+``TelemetryRecorder``.
 
 The Chrome trace event format (the JSON Perfetto's legacy importer and
 chrome://tracing both load) is an object ``{"traceEvents": [...]}`` whose
@@ -96,16 +96,6 @@ def write_trace(recorder: TelemetryRecorder, path: str) -> Dict[str, Any]:
     with open(path, "w") as f:
         json.dump(trace, f)
     return trace
-
-
-def write_events_jsonl(recorder: TelemetryRecorder, path: str) -> int:
-    """Append-free JSONL dump of the (bounded) global event log."""
-    n = 0
-    with open(path, "w") as f:
-        for e in recorder.events:
-            f.write(json.dumps(e) + "\n")
-            n += 1
-    return n
 
 
 # ------------------------------------------------------------ validation

@@ -16,6 +16,7 @@ from jax.sharding import PartitionSpec
 
 from repro.configs.base import ModelConfig
 from repro.launch import steps as steps_lib
+from repro.models import ffn
 from repro.optim.adamw import OptimizerConfig
 from repro.train import checkpoint
 from repro.train import state as S
@@ -30,6 +31,10 @@ class TrainerConfig:
     keep_checkpoints: int = 3
     log_interval: int = 10
     loss_chunk: int = 512
+
+
+def _span(name: str, **args) -> jax.profiler.TraceAnnotation:
+    return jax.profiler.TraceAnnotation(f"spt.train.{name}", **args)
 
 
 class Trainer:
@@ -87,8 +92,20 @@ class Trainer:
 
     def _save(self, step: int) -> None:
         if self.tcfg.ckpt_dir:
-            checkpoint.save(self.state, step, self.tcfg.ckpt_dir,
-                            keep=self.tcfg.keep_checkpoints)
+            with _span("checkpoint"):
+                checkpoint.save(self.state, step, self.tcfg.ckpt_dir,
+                                keep=self.tcfg.keep_checkpoints)
+
+    def _count(self, batch: Dict[str, jax.Array], metrics: dict) -> None:
+        """The step's routed-FFN slot counts as a zero-length profiler
+        event, from shapes and the ``dropped`` already on the host."""
+        b, s = np.shape(batch["tokens"])
+        if self.cfg.frontend_tokens and "frontend_embeds" in batch:
+            s += np.shape(batch["frontend_embeds"])[1]
+        counts = ffn.slot_counts(self.cfg, b, s, metrics["dropped"])
+        if counts is not None:
+            with _span("counters", **counts):
+                pass
 
     def _place(self, batch: Dict[str, np.ndarray]) -> Dict[str, jax.Array]:
         if self._state_sh is None:
@@ -100,28 +117,47 @@ class Trainer:
 
     def run(self, data: Iterator[Dict[str, np.ndarray]],
             step_hook: Optional[Callable[[int, dict], None]] = None) -> dict:
+        """Train until ``total_steps``, the data's end or SIGTERM.
+
+        Each step is a profiler step span ``spt.train`` (``step_num`` the
+        step's index) with children ``spt.train.next_batch``, ``.place``,
+        ``.dispatch``, ``.fetch``, ``.hook`` and, when a save runs,
+        ``.checkpoint``; after the fetch, a zero-length
+        ``spt.train.counters`` carries the routed FFN's ``slot_counts``
+        where the model routes. Without a running profiler the spans cost
+        a few microseconds a step."""
         step = self.start_step
-        for batch in data:
-            if step >= self.tcfg.total_steps or self._stop:
-                break
-            batch = self._place(batch)
-            self.monitor.start()
-            self.state, metrics = self._step(self.state, batch)
-            metrics = {k: float(v) for k, v in metrics.items()}
-            self.monitor.stop(step)
-            step += 1
-            if step % self.tcfg.log_interval == 0 or step == 1:
-                self.metrics_log.append({"step": step, **metrics})
-            if step_hook:
-                step_hook(step, metrics)
-            if self.tcfg.ckpt_dir and step % self.tcfg.ckpt_interval == 0:
-                self._save(step)
-            if self.monitor.should_act():
-                # straggler density high: checkpoint eagerly so a scheduler
-                # can replace the slow host with bounded lost work
-                self._save(step)
-                self.monitor.events.append(
-                    {"step": step, "action": "eager_checkpoint"})
+        data = iter(data)
+        while step < self.tcfg.total_steps and not self._stop:
+            with jax.profiler.StepTraceAnnotation("spt.train", step_num=step):
+                with _span("next_batch"):
+                    batch = next(data, None)
+                if batch is None:
+                    break
+                with _span("place"):
+                    batch = self._place(batch)
+                self.monitor.start()
+                with _span("dispatch"):
+                    self.state, metrics = self._step(self.state, batch)
+                with _span("fetch"):
+                    metrics = {k: float(v) for k, v in metrics.items()}
+                self.monitor.stop(step)
+                self._count(batch, metrics)
+                step += 1
+                if step % self.tcfg.log_interval == 0 or step == 1:
+                    self.metrics_log.append({"step": step, **metrics})
+                if step_hook:
+                    with _span("hook"):
+                        step_hook(step, metrics)
+                if self.tcfg.ckpt_dir and step % self.tcfg.ckpt_interval == 0:
+                    self._save(step)
+                if self.monitor.should_act():
+                    # straggler density high: checkpoint eagerly so a
+                    # scheduler can replace the slow host with bounded
+                    # lost work
+                    self._save(step)
+                    self.monitor.events.append(
+                        {"step": step, "action": "eager_checkpoint"})
         self._save(step)
         return {"final_step": step,
                 "metrics": self.metrics_log,

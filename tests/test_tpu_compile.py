@@ -9,6 +9,7 @@ overruns — so these tests guard the chip build on a CPU-only machine.
 The topology is described inside a fixture, never at import time.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +32,8 @@ CFG = configs.get_config("qwen3-0.6b")
 HQ, HK, DH = CFG.num_heads, CFG.num_kv_heads, CFG.resolved_head_dim
 D, M = CFG.d_model, DH // CFG.spt.pq_code_dim
 PS = CFG.spt.kv_page_size
+# The fine-tuning forward's kernels, by the names they carry in the HLO.
+PREFILL = ["pq_assign", "topl_thresholds", "sparse_attention_fwd"]
 
 
 @pytest.fixture(scope="module")
@@ -58,9 +61,13 @@ def spec(topo):
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, kernels=()):
+    """The compiled HLO text; each Pallas call named in ``kernels`` is an
+    instruction of that name (what the profiler's trace shows)."""
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
+    for name in kernels:
+        assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
     return hlo
 
 
@@ -80,7 +87,8 @@ def _ffn_params(spec):
 
 def test_pq_assign(spec):
     _compile(lambda x, cb: pq_ops.pq_assign(x, cb, interpret=False),
-             spec((4, HK, 1024, DH), CFG.dtype), _codebooks(spec))
+             spec((4, HK, 1024, DH), CFG.dtype), _codebooks(spec),
+             kernels=["pq_assign"])
 
 
 def test_sparse_prefill(spec):
@@ -94,7 +102,7 @@ def test_sparse_prefill(spec):
 
     _compile(fwd, spec((b, HQ, s, DH), CFG.dtype),
              spec((b, HK, s, DH), CFG.dtype), spec((b, HK, s, DH), CFG.dtype),
-             _codebooks(spec))
+             _codebooks(spec), kernels=PREFILL)
 
 
 def test_sparse_prefill_float32_highest_precision(spec):
@@ -109,7 +117,8 @@ def test_sparse_prefill_float32_highest_precision(spec):
 
     with jax.default_matmul_precision("highest"):
         _compile(fwd, spec((b, HQ, s, DH), f32), spec((b, HK, s, DH), f32),
-                 spec((b, HK, s, DH), f32), _codebooks(spec))
+                 spec((b, HK, s, DH), f32), _codebooks(spec),
+                 kernels=PREFILL)
 
 
 @pytest.mark.parametrize("fuse", [True, False], ids=["fused", "two_pass"])
@@ -125,7 +134,10 @@ def test_sparse_decode(spec, fuse, s):
     _compile(dec, spec((b, HQ, 1, DH), CFG.dtype),
              spec((b, HK, s, DH), CFG.dtype), spec((b, HK, s, DH), CFG.dtype),
              spec((b, HK, s, M), jnp.int8), _codebooks(spec),
-             spec((b, s), jnp.bool_))
+             spec((b, s), jnp.bool_),
+             kernels=(["sparse_attention_decode_fused"] if fuse
+                      else ["topl_thresholds_decode",
+                            "sparse_attention_decode"]))
 
 
 # A pool that fills most of one chip's KV budget: 28 layers x 800 pages x
@@ -143,7 +155,8 @@ def test_sparse_decode_paged(spec, pool, mp):
              spec((pool, HK, PS, DH), CFG.dtype),
              spec((pool, HK, PS, DH), CFG.dtype),
              spec((pool, HK, PS, M), jnp.int8), _codebooks(spec),
-             spec((b, mp * PS), jnp.bool_), spec((b, mp), jnp.int32))
+             spec((b, mp * PS), jnp.bool_), spec((b, mp), jnp.int32),
+             kernels=["sparse_attention_decode_paged"])
 
 
 def test_sparse_decode_paged_highest_precision(spec):
@@ -163,7 +176,8 @@ def test_dense_decode_paged(spec):
     _compile(dec, spec((b, HQ, 1, DH), CFG.dtype),
              spec((pool, HK, PS, DH), CFG.dtype),
              spec((pool, HK, PS, DH), CFG.dtype),
-             spec((b, mp * PS), jnp.bool_), spec((b, mp), jnp.int32))
+             spec((b, mp * PS), jnp.bool_), spec((b, mp), jnp.int32),
+             kernels=["dense_attention_decode_paged"])
 
 
 def test_routed_ffn_grouped(spec):
@@ -171,7 +185,8 @@ def test_routed_ffn_grouped(spec):
         return rffn_ops.routed_ffn(x, p, ffn._routed_cfg(CFG), CFG.spt.lora,
                                    interpret=False)[0]
 
-    _compile(fwd, _ffn_params(spec), spec((4, 1024, D), CFG.dtype))
+    _compile(fwd, _ffn_params(spec), spec((4, 1024, D), CFG.dtype),
+             kernels=["routed_ffn_grouped"])
 
 
 def test_routed_ffn_decode(spec):
@@ -179,7 +194,8 @@ def test_routed_ffn_decode(spec):
         return rffn_ops.routed_ffn_decode(x, p, ffn._routed_cfg(CFG),
                                           CFG.spt.lora, interpret=False)[0]
 
-    _compile(dec, _ffn_params(spec), spec((8, 1, D), CFG.dtype))
+    _compile(dec, _ffn_params(spec), spec((8, 1, D), CFG.dtype),
+             kernels=["routed_ffn_decode"])
 
 
 @pytest.mark.parametrize("arch", ["recurrentgemma-9b", "grok-1-314b"])
@@ -197,11 +213,12 @@ def test_routed_ffn_wide(spec, arch):
     weights = (w(g, d, f), w(g, f, d), w(g, d, f), lp)
     _compile(lambda x, i, wi, wo, wg, lp: grouped_ffn_kernel(
         x, i, wi, wo, wg, lp, act=cfg.activation, interpret=False),
-        w(2, 1024, d), spec((2, g, 256), jnp.int32), *weights)
+        w(2, 1024, d), spec((2, g, 256), jnp.int32), *weights,
+        kernels=["routed_ffn_grouped"])
     _compile(lambda x, c, gt, wi, wo, wg, lp: decode_ffn_kernel(
         x, c, gt, wi, wo, wg, lp, act=cfg.activation, interpret=False),
         w(8, d), spec((8, 2), jnp.int32), spec((8, 2), jnp.float32),
-        *weights)
+        *weights, kernels=["routed_ffn_decode"])
 
 
 def test_ragged_prefill_batch_one(spec):
