@@ -59,13 +59,20 @@ def assign(x: jax.Array, codebooks: jax.Array) -> jax.Array:
     x:          (..., n, d)  with d = M * d'
     codebooks:  (M, E, d')
     returns codes: (..., n, M) int32 in [0, E)
+
+    Distances are float32 at ``Precision.HIGHEST``, as the Pallas kernel
+    (``kernels/pq_quantize``) computes them: the fused forward's codes and
+    those this function recomputes for the backward, the decode cache and
+    the oracle must be the same codes, and the TPU's default precision
+    rounds the codebooks to bfloat16.
     """
     m, e, dp = codebooks.shape
     *lead, n, d = x.shape
     assert d == m * dp, (x.shape, codebooks.shape)
     xs = x.reshape(*lead, n, m, dp).astype(jnp.float32)
     # ||x - c||^2 = ||x||^2 - 2 x.c + ||c||^2 ; ||x||^2 constant over argmin.
-    dots = jnp.einsum("...nmd,med->...nme", xs, codebooks)
+    dots = jnp.einsum("...nmd,med->...nme", xs, codebooks,
+                      precision=jax.lax.Precision.HIGHEST)
     c2 = jnp.sum(codebooks * codebooks, axis=-1)  # (M, E)
     dist = c2 - 2.0 * dots
     return jnp.argmin(dist, axis=-1).astype(jnp.int32)  # (..., n, M)

@@ -3,7 +3,8 @@
 Train/prefill (`sparse_mha`): pq_assign kernel + bucket-histogram kernel +
 fused attention kernel.  Backward differentiates the masked jnp form
 (``core.sparse_attention.sparse_mha_masked``), which selects the identical
-top-L set (same integer thresholds and tie rule), so the gradient is
+top-L set (the same PQ codes, both computing distances in float32 at
+HIGHEST; the same integer thresholds and tie rule), so the gradient is
 consistent with the fused forward up to float associativity — the same
 contract the paper's unit tests check (§A.2, Figure 11).  The masked form
 and not the gather form: the gather's gradient scatter-adds every selected

@@ -21,20 +21,32 @@ def _cb(head_dim, code_dim=8, e=16, seed=0):
 
 
 # ------------------------------------------------------------ pq_quantize
-@pytest.mark.parametrize("shape,dtype", [
-    ((1, 1, 32, 16), jnp.float32),
-    ((2, 3, 64, 32), jnp.float32),
-    ((2, 2, 48, 64), jnp.bfloat16),
-    ((1, 4, 128, 24), jnp.float32),
-])
-def test_pq_assign_kernel_matches_ref(shape, dtype):
+@pytest.mark.parametrize("shape,dtype,tied", [
+    ((1, 1, 32, 16), jnp.float32, False),
+    ((2, 3, 64, 32), jnp.float32, False),
+    ((2, 2, 48, 64), jnp.bfloat16, False),
+    ((1, 4, 128, 24), jnp.float32, False),
+    # the fine-tuning cells' head dims: qwen3-0.6b (M 16), OPT-2.7B (M 10)
+    ((2, 8, 1024, 128), jnp.bfloat16, False),
+    ((2, 4, 1024, 80), jnp.bfloat16, False),
+    # a serving prefill bucket: n not a multiple of 128
+    ((1, 2, 320, 128), jnp.bfloat16, False),
+    # every codeword twice: exact ties resolve to the first index
+    ((2, 2, 256, 80), jnp.bfloat16, True),
+], ids=["shape0-float32", "shape1-float32", "shape2-bfloat16",
+        "shape3-float32", "qwen3-d128", "opt-d80", "n320", "tied"])
+def test_pq_assign_kernel_matches_ref(shape, dtype, tied):
     from repro.kernels.pq_quantize.ops import pq_assign
     from repro.kernels.pq_quantize.ref import pq_assign_ref
     cfg, cb = _cb(shape[-1], code_dim=8)
+    if tied:
+        cb = cb.at[:, 1::2].set(cb[:, 0::2])
     x = jax.random.normal(jax.random.PRNGKey(1), shape).astype(dtype)
-    got = pq_assign(x, cb, interpret=True)
-    want = pq_assign_ref(x, cb)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got = np.asarray(pq_assign(x, cb, interpret=True))
+    want = np.asarray(pq_assign_ref(x, cb))
+    np.testing.assert_array_equal(got, want)
+    if tied:
+        assert not np.any(got % 2)
 
 
 def test_pq_assign_kernel_tiling_invariance():

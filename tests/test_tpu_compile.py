@@ -71,9 +71,9 @@ def _compile(fn, *args, kernels=()):
     return hlo
 
 
-def _codebooks(spec):
+def _codebooks(spec, cfg=CFG):
     shape = jax.eval_shape(lambda: init_tree(
-        pq.param_defs(attention._pq_config(CFG)),
+        pq.param_defs(attention._pq_config(cfg)),
         jax.random.PRNGKey(0)))["codebooks"]
     return spec(shape.shape, shape.dtype)
 
@@ -85,10 +85,21 @@ def _ffn_params(spec):
     return jax.tree_util.tree_map(lambda s: spec(s.shape, s.dtype), tree)
 
 
-def test_pq_assign(spec):
-    _compile(lambda x, cb: pq_ops.pq_assign(x, cb, interpret=False),
-             spec((4, HK, 1024, DH), CFG.dtype), _codebooks(spec),
-             kernels=["pq_assign"])
+# qwen3-0.6b's K codes (M = 16) and OPT-2.7B's (head dim 80, M = 10: the
+# codebook matrix carries padding rows up to 16); then two serving prefill
+# lengths that are one tile not a multiple of 128: n = 320, and n = 2,112
+# (over 2,048 with no multiple of 128 dividing it).
+@pytest.mark.parametrize("arch,n", [
+    ("qwen3-0.6b", 1024), ("opt-2.7b", 1024),
+    ("qwen3-0.6b", 320), ("qwen3-0.6b", 2112),
+], ids=["qwen3-0.6b", "opt-2.7b", "qwen3-0.6b-n320", "qwen3-0.6b-n2112"])
+def test_pq_assign(spec, arch, n):
+    cfg = configs.get_config(arch)
+    hlo = _compile(lambda x, cb: pq_ops.pq_assign(x, cb, interpret=False),
+                   spec((4, cfg.num_kv_heads, n, cfg.resolved_head_dim),
+                        cfg.dtype), _codebooks(spec, cfg),
+                   kernels=["pq_assign"])
+    assert len(re.findall(r"%pq_assign(\.\d+)? = ", hlo)) == 1
 
 
 def test_sparse_prefill(spec):
