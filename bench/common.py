@@ -32,18 +32,35 @@ def benchmark(root: pathlib.Path = ROOT) -> dict:
 
 def load_module(path: pathlib.Path, name: Optional[str] = None):
     """Import a file of the benchmark by its path (metric files carry dots
-    in their names, so they are not importable as modules)."""
+    in their names, so they are not importable as modules). The module is
+    entered in ``sys.modules`` under ``name``, as dataclasses need."""
     name = name or "bench_" + path.stem.replace(".", "_").replace("-", "_")
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
+
+
+def named_module(config: dict, key: str, bench_dir: pathlib.Path = BENCH):
+    """The module that a configuration file names under ``key``:
+    ``<bench_dir>/<key>/<config[key]>.py`` ("reference": the model's plain
+    reference; "work": its count of operations and bytes)."""
+    if key not in config:
+        raise KeyError(f"the configuration has no {key!r} key: it names the "
+                       f"file bench/{key}/<name>.py that knows its model")
+    path = bench_dir / key / f"{config[key]}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"the configuration's {key!r} key names "
+                                f"{config[key]!r}, but {path} is not there")
+    return load_module(path, f"bench_{key}_{path.stem.replace('-', '_')}")
 
 
 class Cell:
     """One workload of BENCHMARK.json with the files its names point to:
     ``configs/<config>.json``, ``traffic/<traffic>.json`` and
-    ``limits/<cell>.json``."""
+    ``limits/<cell>.json``; the configuration names its reference and work
+    modules (``reference_of``, ``work_of``)."""
 
     def __init__(self, name: str, bench: Optional[dict] = None,
                  bench_dir: pathlib.Path = BENCH):
@@ -53,6 +70,7 @@ class Cell:
         if entry is None:
             raise KeyError(f"no workload {name!r} in BENCHMARK.json")
         self.name = name
+        self.bench_dir = bench_dir
         self.entry = entry
         self.bench = bench
         self.config_entry = next(c for c in bench["configs"]
@@ -66,10 +84,10 @@ class Cell:
 
     @classmethod
     def from_parts(cls, name: str, bench: dict, config: dict, traffic: dict,
-                   limits: dict) -> "Cell":
+                   limits: dict, bench_dir: pathlib.Path = BENCH) -> "Cell":
         """A cell from given contents (tests build small ones)."""
         cell = cls.__new__(cls)
-        cell.name, cell.bench = name, bench
+        cell.name, cell.bench, cell.bench_dir = name, bench, bench_dir
         cell.entry = next(w for w in bench["workloads"] if w["name"] == name)
         cell.config_entry = next(c for c in bench["configs"]
                                  if c["name"] == cell.entry["config"])
@@ -86,6 +104,16 @@ class Cell:
         """Per-layer metrics read in this cell: those that list it."""
         return [m for m in self.bench["per_layer"]
                 if self.name in m["workloads"]]
+
+
+def reference_of(cell: Cell):
+    """The cell's reference module (see ``bench/reference/spt.py``)."""
+    return named_module(cell.config, "reference", cell.bench_dir)
+
+
+def work_of(cell: Cell):
+    """The cell's work module (see ``bench/work/spt.py``)."""
+    return named_module(cell.config, "work", cell.bench_dir)
 
 
 def seed_words(seed: int) -> tuple:

@@ -1,6 +1,7 @@
 """Fine-tuning job: the program's ``Trainer.run`` (the ``launch/train.py``
 path) on batches of the cell's traffic, then the float32 reference over the
-first steps.
+first steps. The reference is the module that the cell's configuration
+names (``common.reference_of``): its model, loss, weight rules and layout.
 
 Set-up makes the trainer once, with the benchmark's weights from the seed,
 and drives it through the checked steps with the window's own call and
@@ -34,7 +35,7 @@ from unittest import mock
 import numpy as np
 
 from bench import common, program, weights
-from bench.reference import spt as ref
+from bench.reference import common as ref_common
 
 TINY_GRAD = 1e-3
 common.add_program_path()
@@ -62,28 +63,30 @@ def optimizer(traffic: dict):
                            schedule=o["schedule"], total_steps=1 << 30)
 
 
-def adam(traffic: dict) -> ref.AdamW:
+def adam(traffic: dict) -> ref_common.AdamW:
     o = traffic["optimizer"]
     if o["schedule"] != "constant" or o["warmup_steps"] != 0:
         raise ValueError("the reference follows a constant learning rate")
-    return ref.AdamW(lr=o["lr"], b1=o["b1"], b2=o["b2"], eps=o["eps"],
-                     weight_decay=o["weight_decay"], grad_clip=o["grad_clip"])
+    return ref_common.AdamW(lr=o["lr"], b1=o["b1"], b2=o["b2"],
+                            eps=o["eps"], weight_decay=o["weight_decay"],
+                            grad_clip=o["grad_clip"])
 
 
-def state_maker(cfg, model: dict, seed: int):
+def state_maker(cfg, model: dict, seed: int, ref):
     """(cfg, key) -> state, as the Trainer calls its initialiser (jitted,
-    with PRNGKey of the seed's low word, given as its ``seed``)."""
+    with PRNGKey of the seed's low word, given as its ``seed``), by the
+    weight rules of the reference module ``ref``."""
     from repro.train import state as S
     abstract = S.abstract_state(cfg)
     hi, _ = common.seed_words(seed)
     return lambda _cfg, key: weights.make_state(
-        abstract, model, common.fold_high(key, hi))
+        abstract, model, common.fold_high(key, hi), ref)
 
 
-def make_weights(abstract, model: dict, seed: int):
+def make_weights(abstract, model: dict, seed: int, ref):
     """The seed's weights in one jitted call, the key an argument."""
     import jax
-    return jax.jit(lambda k: weights.make_state(abstract, model, k))(
+    return jax.jit(lambda k: weights.make_state(abstract, model, k, ref))(
         common.prng_key(seed))
 
 
@@ -91,10 +94,10 @@ def _norms(leaves: Dict[str, np.ndarray]) -> Dict[str, float]:
     return {k: float(np.linalg.norm(v)) for k, v in leaves.items()}
 
 
-def _per_layer(train_tree, layers: int) -> Dict[str, np.ndarray]:
+def _per_layer(train_tree, ref, m) -> Dict[str, np.ndarray]:
     """A program "train" subtree as per-layer leaves, reference names."""
-    t, _ = weights.reference_tree({"train": train_tree, "frozen": {}})
-    return weights.layer_leaves(t, layers)
+    t, _ = weights.reference_tree({"train": train_tree, "frozen": {}}, ref)
+    return ref.per_layer(t, m)
 
 
 def run(cell: common.Cell, seed: int, seconds: float, ctx) -> dict:
@@ -107,14 +110,14 @@ def run(cell: common.Cell, seed: int, seconds: float, ctx) -> dict:
     if getattr(ctx, "control", None):
         return {"numbers": control(cell, seed, ctx.control)}
     traffic, model = cell.traffic, cell.config["model"]
-    cfg = program.model_config(cell.config)
+    ref = common.reference_of(cell)
+    cfg = program.model_config(cell.config, cell.bench_dir)
     m = ref.model_from(model)
     vocab = cell.config["source_config"]["vocab_size"]
     pool = batches(traffic, vocab, seed)
     n_check = traffic["checked_steps"]
     tokens_per_step = traffic["batch"] * traffic["seq_len"]
-    layers = model["layers"]
-    make = state_maker(cfg, model, seed)
+    make = state_maker(cfg, model, seed, ref)
     mesh = make_mesh((1, 1), ("data", "model"), devices=ctx.devices[:1])
     rules = rules_for_mesh(mesh)
 
@@ -129,8 +132,8 @@ def run(cell: common.Cell, seed: int, seconds: float, ctx) -> dict:
         _check_seams(trainer)
         step_fn = trainer._step
         trainer._step = lambda st, b: _spanned(step_fn, st, b)
-        p0 = _per_layer(jax.device_get(trainer.state["train"]), layers)
-        _check_weights(p0, _initial(cell, seed, layers))
+        p0 = _per_layer(jax.device_get(trainer.state["train"]), ref, m)
+        _check_weights(p0, _initial(cell, seed))
         seen = {"loss": []}
 
         def hook(step, metrics):
@@ -138,10 +141,10 @@ def run(cell: common.Cell, seed: int, seconds: float, ctx) -> dict:
                 seen["loss"].append(metrics["loss"])
                 if step == 1:
                     seen["m1"] = _per_layer(
-                        jax.device_get(trainer.state["opt"]["m"]), layers)
+                        jax.device_get(trainer.state["opt"]["m"]), ref, m)
                 if step == n_check:
                     seen["p"] = _per_layer(
-                        jax.device_get(trainer.state["train"]), layers)
+                        jax.device_get(trainer.state["train"]), ref, m)
 
         ctx.note(f"set-up: {n_check} checked steps")
         trainer.run(_feed(pool[:n_check]), step_hook=hook)
@@ -243,28 +246,29 @@ def _free_device(jax) -> None:
 
 
 def reference_steps(cell: common.Cell, seed: int, checked: List[Dict],
-                    dots: ref.Dots):
-    """The reference (or, with lower-precision dots, the control) over the
-    checked steps from the seed's weights: (losses, step-one gradients,
-    parameters after the steps), each per layer."""
-    import jax
+                    dots: ref_common.Dots):
+    """The cell's reference (or, with lower-precision dots, the control)
+    over the checked steps from the seed's weights: (losses, step-one
+    gradients, parameters after the steps), each per layer."""
     import jax.numpy as jnp
     from repro.train import state as S
-    cfg = program.model_config(cell.config)
+    ref = common.reference_of(cell)
+    cfg = program.model_config(cell.config, cell.bench_dir)
     model = cell.config["model"]
     m = ref.model_from(model)
     abstract = {k: v for k, v in S.abstract_state(cfg).items()
                 if k in ("train", "frozen")}
-    state = make_weights(abstract, model, seed)
-    train, frozen = weights.reference_tree(state)
+    state = make_weights(abstract, model, seed, ref)
+    train, frozen = weights.reference_tree(state, ref)
     del state
     data = [(jnp.asarray(b["tokens"]), jnp.asarray(b["labels"]))
             for b in checked]
-    losses, g1, p = ref.run_train_steps(train, frozen, data, m, dots,
-                                        adam(cell.traffic))
+    losses, g1, p = ref_common.run_train_steps(
+        train, frozen, data,
+        lambda tr, fr, tok, lab: ref.train_loss(tr, fr, tok, lab, m, dots),
+        adam(cell.traffic))
     del train, frozen
-    return (losses, weights.layer_leaves(g1, m.layers),
-            weights.layer_leaves(p, m.layers))
+    return losses, ref.per_layer(g1, m), ref.per_layer(p, m)
 
 
 def compare(seen: dict, p0: Dict, want, b1: float) -> Dict[str, dict]:
@@ -294,7 +298,7 @@ def compare(seen: dict, p0: Dict, want, b1: float) -> Dict[str, dict]:
 def check(cell, seed, checked, seen, p0, ctx) -> Dict[str, dict]:
     import jax
     with jax.default_matmul_precision("highest"):
-        want = reference_steps(cell, seed, checked, ref.Dots("f32"))
+        want = reference_steps(cell, seed, checked, ref_common.Dots("f32"))
     out = compare(seen, p0, want, cell.traffic["optimizer"]["b1"])
     out["reference_losses"] = want[0]
     return out
@@ -308,27 +312,29 @@ def control(cell: common.Cell, seed: int, kind: str) -> Dict[str, dict]:
     import jax
     vocab = cell.config["source_config"]["vocab_size"]
     checked = batches(cell.traffic, vocab, seed)[:cell.traffic["checked_steps"]]
-    layers = cell.config["model"]["layers"]
     b1 = cell.traffic["optimizer"]["b1"]
+    f32 = ref_common.Dots("f32")
     if kind == "half_batch":
         placed = [{k: v[: len(v) // 2] for k, v in b.items()}
                   for b in checked]
-        dots = ref.Dots("f32")
+        dots = f32
     else:
-        placed, dots = checked, ref.Dots(kind)
+        placed, dots = checked, ref_common.Dots(kind)
     with jax.default_matmul_precision("highest"):
         losses, g1, p = reference_steps(cell, seed, placed, dots)
-        want = reference_steps(cell, seed, checked, ref.Dots("f32"))
-    p0 = _initial(cell, seed, layers)
+        want = reference_steps(cell, seed, checked, f32)
+    p0 = _initial(cell, seed)
     seen = {"loss": losses, "m1": {k: v * (1 - b1) for k, v in g1.items()},
             "p": p}
     return compare(seen, p0, want, b1)
 
 
-def _initial(cell: common.Cell, seed: int, layers: int) -> Dict:
-    import jax
+def _initial(cell: common.Cell, seed: int) -> Dict:
+    """The seed's trainable weights, per layer, reference names."""
     from repro.train import state as S
-    cfg = program.model_config(cell.config)
+    ref = common.reference_of(cell)
+    cfg = program.model_config(cell.config, cell.bench_dir)
     abstract = {"train": S.abstract_state(cfg)["train"]}
-    state = make_weights(abstract, cell.config["model"], seed)
-    return _per_layer(state["train"], layers)
+    model = cell.config["model"]
+    state = make_weights(abstract, model, seed, ref)
+    return _per_layer(state["train"], ref, ref.model_from(model))

@@ -1,5 +1,6 @@
-"""Plain float32 reference of an SPT language model: fine-tuning steps and a
-full causal forward pass, in straightforward ``jax.numpy``.
+"""Plain float32 reference of an SPT language model: its fine-tuning loss
+in straightforward ``jax.numpy``, and what the benchmark needs to know of
+the model to start the program and the reference from the same weights.
 
 It imports nothing of the program under test. It follows the SPT paper
 (sparse MHA over PQ-scored top-L keys, routed FFN over G' of G weight
@@ -16,73 +17,31 @@ blocks, LoRA adapters) and the semantics that the program documents:
   token order, and a pair past it is dropped. A kept group adds
   act(x W_I[g]) W_O[g], with LoRA on each projection.
 * The loss is the mean next-token cross-entropy plus the switch-style
-  load-balance term, weighted and averaged over layers. AdamW updates the
-  trainable leaves (LoRA, router, codebooks) after clipping by the global
-  gradient norm.
+  load-balance term, weighted and averaged over layers. AdamW
+  (``common.run_train_steps``) updates the trainable leaves (LoRA, router,
+  codebooks) after clipping by the global gradient norm.
 
-Every matrix product goes through ``Dots``: float32 at "highest" precision
-for the reference, or operands rounded to float8 for the control
-(``Dots("fp8")``: e4m3 forward operands, e5m2 gradients, as float8
-training computes).
+Every matrix product goes through ``common.Dots``: float32 at "highest"
+precision for the reference, or operands rounded to float8 for the control.
+
+A configuration file names this module as its ``"reference"``. What a
+reference module supplies: ``model_from``, ``train_loss``,
+``PROGRAM_FIELDS``, ``weight_std``, ``weight_const``, ``reference_path``
+and ``per_layer``.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional, Sequence
+import math
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-F32 = jnp.float32
-HIGHEST = jax.lax.Precision.HIGHEST
+from bench import weights
+from bench.reference.common import F32, Dots, combine
 
-
-# ------------------------------------------------------------ precision
-def _round_fp8(x: jax.Array, dtype=jnp.float8_e4m3fn) -> jax.Array:
-    """Per-tensor scaled float8 rounding, back in float32."""
-    amax = jnp.max(jnp.abs(x))
-    scale = jnp.where(amax > 0, float(jnp.finfo(dtype).max) / amax, 1.0)
-    return (x * scale).astype(dtype).astype(F32) / scale
-
-
-@jax.custom_vjp
-def _fp8_grad(y):
-    return y
-
-
-def _fp8_grad_fwd(y):
-    return y, None
-
-
-def _fp8_grad_bwd(_, g):
-    return (_round_fp8(g, jnp.float8_e5m2),)
-
-
-_fp8_grad.defvjp(_fp8_grad_fwd, _fp8_grad_bwd)
-
-
-def _fp8_value(x):
-    """Forward value rounded to fp8; the gradient passes straight through."""
-    return x + jax.lax.stop_gradient(_round_fp8(x) - x)
-
-
-@dataclasses.dataclass(frozen=True)
-class Dots:
-    """How matrix products are computed: "f32" (the reference) or "fp8"
-    (the control, as float8 training computes: both operands rounded to
-    per-tensor scaled e4m3, the incoming gradient of the product to e5m2;
-    float32 accumulation)."""
-    mode: str = "f32"
-
-    def __call__(self, eq: str, a: jax.Array, b: jax.Array) -> jax.Array:
-        a, b = a.astype(F32), b.astype(F32)
-        if self.mode == "f32":
-            return jnp.einsum(eq, a, b, precision=HIGHEST)
-        if self.mode == "fp8":
-            y = jnp.einsum(eq, _fp8_value(a), _fp8_value(b),
-                           precision=HIGHEST)
-            return _fp8_grad(y)
-        raise ValueError(self.mode)
+Path = Tuple[str, ...]
 
 
 # ------------------------------------------------------------ config
@@ -330,67 +289,99 @@ def train_loss(train, frozen, tokens, labels, m: Model, dot: Dots):
     return lm + m.lb_weight * lb / m.layers
 
 
-@dataclasses.dataclass(frozen=True)
-class AdamW:
-    lr: float
-    b1: float = 0.9
-    b2: float = 0.999
-    eps: float = 1e-8
-    weight_decay: float = 0.01
-    grad_clip: float = 1.0
-
-
-def train_step(train, frozen, opt, step, tokens, labels, m: Model,
-               dot: Dots, adam: AdamW):
-    """One SPT fine-tuning step: returns (train', opt', loss, clipped grads)."""
-    loss, g = jax.value_and_grad(train_loss)(train, frozen, tokens, labels,
-                                             m, dot)
-    gnorm = jnp.sqrt(sum(jnp.sum(x * x)
-                         for x in jax.tree_util.tree_leaves(g)))
-    clip = jnp.minimum(1.0, adam.grad_clip / (gnorm + 1e-12))
-    g = jax.tree_util.tree_map(lambda x: x * clip, g)
-    t = step + 1.0
-    mo = jax.tree_util.tree_map(lambda a, x: adam.b1 * a + (1 - adam.b1) * x,
-                                opt["m"], g)
-    vo = jax.tree_util.tree_map(
-        lambda a, x: adam.b2 * a + (1 - adam.b2) * x * x, opt["v"], g)
-
-    def upd(p, a, b):
-        step_ = (a / (1 - adam.b1 ** t)) / (
-            jnp.sqrt(b / (1 - adam.b2 ** t)) + adam.eps)
-        return p - adam.lr * (step_ + adam.weight_decay * p)
-
-    new = jax.tree_util.tree_map(upd, train, mo, vo)
-    return new, {"m": mo, "v": vo}, loss, g
-
-
-def combine(a, b):
-    """Merge two trees with disjoint leaves (trainable + frozen)."""
-    if a is None or b is None:
-        return b if a is None else a
-    return {k: combine(a.get(k), b.get(k)) for k in set(a) | set(b)}
-
-
-def run_train_steps(train, frozen, batches: Sequence, m: Model, dot: Dots,
-                    adam: AdamW):
-    """Follow len(batches) steps from (train, zero moments). Returns
-    (losses, clipped grads of step 1, train params after the last step)."""
-    step = jax.jit(lambda tr, fr, op, st, tok, lab: train_step(
-        tr, fr, op, st, tok, lab, m, dot, adam))
-    opt = {"m": jax.tree_util.tree_map(jnp.zeros_like, train),
-           "v": jax.tree_util.tree_map(jnp.zeros_like, train)}
-    losses, first = [], None
-    for i, (tok, lab) in enumerate(batches):
-        train, opt, loss, g = step(train, frozen, opt, jnp.float32(i), tok,
-                                   lab)
-        losses.append(float(loss))
-        if first is None:
-            first = jax.device_get(g)
-    return losses, first, jax.device_get(train)
-
-
 def model_from(cfg: Dict) -> Model:
-    """A Model from the benchmark's configuration file (its "reference"
-    block)."""
+    """A Model from the configuration file's "model" block."""
     fields = {f.name for f in dataclasses.fields(Model)}
     return Model(**{k: v for k, v in cfg.items() if k in fields})
+
+
+# ------------------------------------------------------------ the program
+def _learned_positions(cfg) -> bool:
+    return cfg.positional == "learned"
+
+
+# reference field -> program field (ModelConfig, or its SPTConfig as spt.*,
+# or a function of the ModelConfig)
+PROGRAM_FIELDS = {
+    "layers": "num_layers", "d_model": "d_model", "heads": "num_heads",
+    "kv_heads": "num_kv_heads", "head_dim": "resolved_head_dim",
+    "d_ff": "d_ff", "vocab_rows": "padded_vocab", "norm": "norm",
+    "qk_norm": "qk_norm", "rope_theta": "rope_theta", "gated": "gated_ffn",
+    "activation": "activation", "tie_embeddings": "tie_embeddings",
+    "code_dim": "spt.pq_code_dim", "codewords": "spt.pq_codewords",
+    "top_fraction": "spt.attn_top_fraction", "min_l": "spt.attn_min_l",
+    "pad_l_to": "spt.attn_pad_l_to", "groups": "spt.ffn_groups",
+    "active_groups": "spt.ffn_active_groups",
+    "capacity_factor": "spt.ffn_capacity_factor",
+    "capacity_pad": "spt.dispatch_pad", "lora_rank": "spt.lora.rank",
+    "lora_alpha": "spt.lora.alpha", "lb_weight": "spt.lb_loss_weight",
+    "learned_pos": _learned_positions,
+}
+
+
+def weight_std(path: Path, shape, model: dict) -> float:
+    """Standard deviation of the program's leaf at ``path`` ("train" or
+    "frozen" first); 0 for a constant (see ``weight_const``).
+
+    Projections by 1/sqrt(fan-in), embeddings at 0.02, norms at one and
+    zero, codebooks at unit scale (the size of a normalised head's
+    sub-vector), and the frozen weights that write the residual stream (the
+    attention and FFN outputs) further by the configuration's
+    ``residual_gain``. SPT's discrete choices (PQ codes, top-L keys, top-G'
+    groups) flip under any rounding; with GPT-2 scales, 28 random layers
+    amplify the flips until bfloat16 and float32 runs of one model share
+    no more than their statistics. A small residual gain (as SkipInit and
+    ReZero start) keeps the stack well-conditioned. The residual stream
+    then stays near the token's embedding, so the final norm's scale is
+    drawn around zero (unit normal): with a tied head, a scale of one would
+    make every token predict itself.
+
+    Adapters: the two that write the residual stream start as LoRA starts,
+    their up factor at zero; the others are taken mid-run, both factors
+    non-zero, so that their every leaf has a gradient at step one."""
+    name, parent = path[-1], path[-2] if len(path) > 1 else ""
+    residual = model["residual_gain"]
+    if name in ("embedding", "pos_embedding"):
+        return 0.02
+    if name == "codebooks":
+        return 1.0
+    if parent == "final_norm" and name == "scale":
+        return 1.0
+    if name in ("scale", "bias") and "norm" in parent:
+        return 0.0
+    if name == "c" and len(path) > 2:                  # LoRA up factor
+        if "wo" in path or "lora_outer" in path:       # writes the residual
+            return 0.0
+        return 0.1 / math.sqrt(shape[-2])
+    if name == "w_outer":
+        return residual / math.sqrt(shape[-2] * model["active_groups"])
+    if parent == "wo" and name == "w":
+        return residual / math.sqrt(shape[-2])
+    return 1.0 / math.sqrt(shape[-2])
+
+
+def weight_const(path: Path) -> float:
+    """The value of a leaf whose ``weight_std`` is 0."""
+    return 1.0 if path[-1] == "scale" else 0.0
+
+
+# program layout -> reference layout
+_RENAME = {("embed", "embedding"): ("embed",),
+           ("pos", "pos_embedding"): ("pos",),
+           ("head", "w"): ("head",)}
+
+
+def reference_path(path: Path) -> Path:
+    """('frozen','units','b0_attn','mixer','wq','lora','b') ->
+    ('layers','attn','wq','b'), and so on."""
+    p = weights.model_path(path)
+    if p[:1] == ("units",):
+        p = ("layers",) + p[2:]
+        p = tuple("attn" if s == "mixer" else s for s in p)
+        p = tuple(s for s in p if s not in ("lora", "pq"))
+    return _RENAME.get(p, p)
+
+
+def per_layer(tree: dict, m: Model) -> Dict:
+    """A reference-layout tree as per-layer leaves ("layers/3/attn/wq/b")."""
+    return weights.layer_leaves(tree, m.layers)

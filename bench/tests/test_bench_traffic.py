@@ -33,16 +33,17 @@ def test_weights_repeat_for_a_seed_whatever_the_tree_around_them():
     from bench import program, weights
     from repro.train import state as S
     cell = tiny.cell()
+    ref = common.reference_of(cell)
     cfg = program.model_config(cell.config)
     full = S.abstract_state(cfg)
     part = {"train": full["train"]}
     key = common.prng_key(2 ** 31 + 3)
-    a = weights.make_state(full, cell.config["model"], key)
-    b = weights.make_state(part, cell.config["model"], key)
+    a = weights.make_state(full, cell.config["model"], key, ref)
+    b = weights.make_state(part, cell.config["model"], key, ref)
     la = jax.tree_util.tree_leaves(a["train"])
     lb = jax.tree_util.tree_leaves(b["train"])
     assert all(np.array_equal(x, y) for x, y in zip(la, lb))
-    t, _ = weights.reference_tree({"train": b["train"], "frozen": {}})
+    t, _ = weights.reference_tree({"train": b["train"], "frozen": {}}, ref)
     for name, leaf in weights.layer_leaves(t, 2).items():
         # LoRA up factors that write the residual stream start at zero
         zero = name.endswith(("wo/c", "lora_outer/c"))
@@ -54,18 +55,19 @@ def test_one_compiled_program_makes_every_seeds_weights():
     from bench import program
     from repro.train import state as S
     cell = tiny.cell()
+    ref = common.reference_of(cell)
     cfg = program.model_config(cell.config)
     model = cell.config["model"]
 
     def text(seed):
-        maker = train.state_maker(cfg, model, seed)
+        maker = train.state_maker(cfg, model, seed, ref)
         return jax.jit(maker, static_argnums=0).lower(
             cfg, jax.random.PRNGKey(common.seed_words(seed)[1])).as_text()
 
     assert text(2 ** 31 + 1) == text(2 ** 31 + 99)
     # the trainer's key, from the low word, gives the seed's weights
-    a = jax.jit(train.state_maker(cfg, model, 2 ** 33 + 7),
+    a = jax.jit(train.state_maker(cfg, model, 2 ** 33 + 7, ref),
                 static_argnums=0)(cfg, jax.random.PRNGKey(7))
-    b = train.make_weights(S.abstract_state(cfg), model, 2 ** 33 + 7)
+    b = train.make_weights(S.abstract_state(cfg), model, 2 ** 33 + 7, ref)
     assert all(np.array_equal(x, y) for x, y in zip(
         jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)))
